@@ -23,7 +23,8 @@ train step; each async write records how many steps completed while it
 was in flight (``ckpt.overlapped_steps``) — the acceptance metric for
 "checkpointing overlaps training".  All lifecycle durations and queue
 depth emit through the ``repro.obs`` registry, and snapshot/write/restore
-show up as spans (the writer gets its own trace lane).
+show up as ``Observability.span`` s (``ckpt.snapshot`` / ``ckpt.write`` /
+``ckpt.restore``; the writer thread's land on its own trace line).
 
 Elastic restore: ``restore(like, shardings=...)`` accepts a shardings
 pytree built for the *current* mesh (``repro.dist.sharding.tree_shardings``
@@ -33,7 +34,6 @@ treedef is validated against ``like`` before any leaf loads.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import queue
 import threading
@@ -43,6 +43,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 from repro.ckpt import checkpoint as ckpt
+from repro.obs import no_span
 
 #: trace lane for the background writer (0 is the caller's lane)
 WRITER_LANE = 9
@@ -100,9 +101,9 @@ class CheckpointManager:
         self.write_throttle_s = write_throttle_s
         self.saves: List[SaveRecord] = []
         self._registry = obs.registry if obs is not None else None
-        self._tracer = getattr(obs, "tracer", None) if obs is not None else None
-        if self._tracer is not None:
-            self._tracer.set_thread_name(WRITER_LANE, "ckpt-writer")
+        self._span = obs.span if obs is not None else no_span
+        if obs is not None and obs.tracer is not None:
+            obs.tracer.set_thread_name(WRITER_LANE, "ckpt-writer")
         self._queue: "queue.Queue[Optional[_Job]]" = queue.Queue(
             maxsize=max(1, max_in_flight))
         self._writer: Optional[threading.Thread] = None
@@ -114,11 +115,6 @@ class CheckpointManager:
             self._registry.counter("ckpt.torn_tmp_cleaned", len(removed))
 
     # -- obs helpers -------------------------------------------------------
-
-    def _span(self, name: str, tid: int = 0, **args):
-        if self._tracer is None:
-            return contextlib.nullcontext()
-        return self._tracer.span(name, tid=tid, **args)
 
     def _observe(self, name: str, value: float, **labels) -> None:
         if self._registry is not None:

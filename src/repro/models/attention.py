@@ -97,6 +97,7 @@ def _sdpa_chunked(q, k, v, q_pos, kv_pos, *, causal, window, prefix_len,
     return acc / jnp.maximum(l, 1e-30)[..., None]
 
 
+@jax.named_scope("attention")
 def sdpa(q, k, v, q_pos, kv_pos, *, causal=True, window=None,
          prefix_len=None, chunk=1024, softcap=None):
     """Grouped SDPA. q: (B,Sq,H,D) k/v: (B,Skv,KH,D[v]) -> (B,Sq,H,Dv)."""
@@ -419,6 +420,7 @@ def init_cache(spec) -> Dict[str, jax.Array]:
                 else jnp.zeros(v.shape, v.dtype)) for k, v in spec.items()}
 
 
+@jax.named_scope("kv_write")
 def _cache_write(cache: Dict[str, jax.Array], updates: Dict[str, jax.Array],
                  pos: jax.Array) -> Dict[str, jax.Array]:
     """Write one token (Sq=1) at absolute position ``pos``.
@@ -452,6 +454,7 @@ def _cache_write(cache: Dict[str, jax.Array], updates: Dict[str, jax.Array],
     return new
 
 
+@jax.named_scope("kv_write")
 def _cache_append(cache: Dict[str, jax.Array], updates: Dict[str, jax.Array],
                   q_pos: jax.Array) -> Dict[str, jax.Array]:
     """Append a contiguous chunk at slots [p0, p0+Sq) (chunked prefill).
@@ -557,13 +560,15 @@ def gqa_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
                 # fused path: codes stream into the kernel and dequantise
                 # in VMEM — the cache is never expanded to bf16/f32 in HBM
                 from repro.kernels import attention as kq
-                qg = q[:, 0].reshape(b, kh, h_loc // kh, d).astype(jnp.float32)
-                qg = qg / jnp.sqrt(jnp.float32(d))
-                o = kq.bp8_decode_attention(
-                    qg, new_cache["k_codes"], new_cache["k_scale"],
-                    new_cache["v_codes"], new_cache["v_scale"],
-                    new_cache["pos"], q_pos[:, 0], window,
-                    softcap=cfg.logit_softcap, causal=causal)
+                with jax.named_scope("attention"):
+                    qg = q[:, 0].reshape(b, kh, h_loc // kh,
+                                         d).astype(jnp.float32)
+                    qg = qg / jnp.sqrt(jnp.float32(d))
+                    o = kq.bp8_decode_attention(
+                        qg, new_cache["k_codes"], new_cache["k_scale"],
+                        new_cache["v_codes"], new_cache["v_scale"],
+                        new_cache["pos"], q_pos[:, 0], window,
+                        softcap=cfg.logit_softcap, causal=causal)
                 out = o.reshape(b, 1, h_loc, -1)
                 k_all = v_all = kv_pos = None
             elif quant:
@@ -631,11 +636,12 @@ def gqa_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
             # sequence parallelism: ring-attend the seq-sharded KV inside
             # a manual shard_map region; falls through to plain sdpa when
             # the ambient rules leave this KV unsharded on the ring axis
-            out = msq.ring_attend(
-                q, k_all, v_all, q_pos, kv_pos,
-                kv_logical="kv_seq" if cache is not None else "seq",
-                causal=causal, window=window, prefix_len=prefix_len,
-                softcap=cfg.logit_softcap)
+            with jax.named_scope("attention"):
+                out = msq.ring_attend(
+                    q, k_all, v_all, q_pos, kv_pos,
+                    kv_logical="kv_seq" if cache is not None else "seq",
+                    causal=causal, window=window, prefix_len=prefix_len,
+                    softcap=cfg.logit_softcap)
         if out is None:
             out = sdpa(q, k_all, v_all, q_pos, kv_pos,
                        causal=causal and cross_kv is None, window=window,
@@ -731,25 +737,28 @@ def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
                         p["wuk"].astype(jnp.float32))
         from repro.dist import seq as msq
         o_lat = None
-        if msq.current_ring() is not None:
-            # sequence parallelism: ring over the seq-sharded latent cache;
-            # scores and the latent accumulator stay inside the manual
-            # region, the W_uv expansion below runs on the auto partitioner
-            o_lat = msq.ring_attend_mla(
-                qa, q_rope.astype(jnp.float32), new_cache["ckv"],
-                new_cache["krope"], q_pos, kv_pos, window=window, scale=scale)
-        if o_lat is None:
-            ckv_all = new_cache["ckv"].astype(jnp.float32)    # (B, S, R)
-            kr_all = new_cache["krope"].astype(jnp.float32)   # (B, S, P)
-            s_nope = jnp.einsum("bqhr,bsr->bhqs", qa, ckv_all)
-            s_rope = jnp.einsum("bqhp,bsp->bhqs", q_rope.astype(jnp.float32),
-                                kr_all)
-            scores = (s_nope + s_rope) * scale
-            mask = _allowed(q_pos, kv_pos, causal=True, window=window,
-                            prefix_len=None)
-            scores = jnp.where(mask[:, None], scores, NEG_INF)
-            pr = jax.nn.softmax(scores, axis=-1)
-            o_lat = jnp.einsum("bhqs,bsr->bqhr", pr, ckv_all)  # (B,1,H,R)
+        with jax.named_scope("attention"):
+            if msq.current_ring() is not None:
+                # sequence parallelism: ring over the seq-sharded latent
+                # cache; scores and the latent accumulator stay inside the
+                # manual region, the W_uv expansion below runs on the auto
+                # partitioner
+                o_lat = msq.ring_attend_mla(
+                    qa, q_rope.astype(jnp.float32), new_cache["ckv"],
+                    new_cache["krope"], q_pos, kv_pos, window=window,
+                    scale=scale)
+            if o_lat is None:
+                ckv_all = new_cache["ckv"].astype(jnp.float32)  # (B, S, R)
+                kr_all = new_cache["krope"].astype(jnp.float32)  # (B, S, P)
+                s_nope = jnp.einsum("bqhr,bsr->bhqs", qa, ckv_all)
+                s_rope = jnp.einsum("bqhp,bsp->bhqs",
+                                    q_rope.astype(jnp.float32), kr_all)
+                scores = (s_nope + s_rope) * scale
+                mask = _allowed(q_pos, kv_pos, causal=True, window=window,
+                                prefix_len=None)
+                scores = jnp.where(mask[:, None], scores, NEG_INF)
+                pr = jax.nn.softmax(scores, axis=-1)
+                o_lat = jnp.einsum("bhqs,bsr->bqhr", pr, ckv_all)  # (B,1,H,R)
         out = jnp.einsum("bqhr,rhv->bqhv", o_lat, p["wuv"].astype(jnp.float32))
     elif cache is not None and append:
         # ---- chunked prefill: append latents, expand K/V from the full

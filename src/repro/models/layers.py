@@ -25,30 +25,32 @@ from repro.models.params import ParamDef
 
 def dense(x: jax.Array, w: jax.Array, mode: str = "bf16",
           bias: Optional[jax.Array] = None) -> jax.Array:
-    """x: (..., K) @ w: (K, N) under the configured matmul mode."""
-    if mode == "bf16":
-        y = jnp.einsum("...k,kn->...n", x, w.astype(x.dtype))
-    elif mode in ("bp8", "bp8_lowrank"):
-        impl = "bitplane" if mode == "bp8" else "lowrank"
-        lead = x.shape[:-1]
-        x2 = _q.exact_f32(x.reshape(-1, x.shape[-1]))
-        y = _bpm.bp_matmul_ste(x2, w.astype(jnp.float32), impl=impl)
-        y = y.reshape(*lead, w.shape[-1]).astype(x.dtype)
-    elif mode == "bp8_fused":
-        from repro.kernels import ops as _kops
-        lead = x.shape[:-1]
-        x2 = _q.exact_f32(x.reshape(-1, x.shape[-1]))
-        y = _kops.oisma_matmul_ste(x2, w.astype(jnp.float32))
-        y = y.reshape(*lead, w.shape[-1]).astype(x.dtype)
-    elif mode == "fp8":
-        xq = _q.fake_quantize_e4m3(x.astype(jnp.float32))
-        wq = _q.fake_quantize_e4m3(w.astype(jnp.float32))
-        y = jnp.einsum("...k,kn->...n", xq, wq).astype(x.dtype)
-    else:
-        raise ValueError(f"unknown matmul mode {mode!r}")
-    if bias is not None:
-        y = y + bias.astype(y.dtype)
-    return y
+    """x: (..., K) @ w: (K, N) under the configured matmul mode, in the
+    name scope ``dense.<mode>`` (the operand conversions with it)."""
+    with jax.named_scope(f"dense.{mode}"):
+        if mode == "bf16":
+            y = jnp.einsum("...k,kn->...n", x, w.astype(x.dtype))
+        elif mode in ("bp8", "bp8_lowrank"):
+            impl = "bitplane" if mode == "bp8" else "lowrank"
+            lead = x.shape[:-1]
+            x2 = _q.exact_f32(x.reshape(-1, x.shape[-1]))
+            y = _bpm.bp_matmul_ste(x2, w.astype(jnp.float32), impl=impl)
+            y = y.reshape(*lead, w.shape[-1]).astype(x.dtype)
+        elif mode == "bp8_fused":
+            from repro.kernels import ops as _kops
+            lead = x.shape[:-1]
+            x2 = _q.exact_f32(x.reshape(-1, x.shape[-1]))
+            y = _kops.oisma_matmul_ste(x2, w.astype(jnp.float32))
+            y = y.reshape(*lead, w.shape[-1]).astype(x.dtype)
+        elif mode == "fp8":
+            xq = _q.fake_quantize_e4m3(x.astype(jnp.float32))
+            wq = _q.fake_quantize_e4m3(w.astype(jnp.float32))
+            y = jnp.einsum("...k,kn->...n", xq, wq).astype(x.dtype)
+        else:
+            raise ValueError(f"unknown matmul mode {mode!r}")
+        if bias is not None:
+            y = y + bias.astype(y.dtype)
+        return y
 
 
 def linear_def(d_in: int, d_out: int, in_axis: str, out_axis: str,
@@ -146,17 +148,18 @@ def mlp_apply(p, x: jax.Array, act: str, gated: bool, mode: str) -> jax.Array:
         # bp8_fused runs it as one grid: up/gate share one in-kernel BP
         # encode of x and the two (tokens, d_ff) projections never reach
         # HBM.  bp8 is its jnp reference, with the same rounding order.
-        lead = x.shape[:-1]
-        x2 = _q.exact_f32(x.reshape(-1, x.shape[-1]))
-        w_up = p["up"].astype(jnp.float32)
-        w_gate = p["gate"].astype(jnp.float32)
-        if mode == "bp8_fused":
-            from repro.kernels import ops as _kops
-            up = _kops.oisma_mlp_ste(x2, w_up, w_gate, act=act)
-        else:
-            up = (activation(_bpm.bp_matmul_ste(x2, w_gate), act)
-                  * _bpm.bp_matmul_ste(x2, w_up))
-        up = up.reshape(*lead, p["up"].shape[-1]).astype(x.dtype)
+        with jax.named_scope(f"dense.{mode}"):
+            lead = x.shape[:-1]
+            x2 = _q.exact_f32(x.reshape(-1, x.shape[-1]))
+            w_up = p["up"].astype(jnp.float32)
+            w_gate = p["gate"].astype(jnp.float32)
+            if mode == "bp8_fused":
+                from repro.kernels import ops as _kops
+                up = _kops.oisma_mlp_ste(x2, w_up, w_gate, act=act)
+            else:
+                up = (activation(_bpm.bp_matmul_ste(x2, w_gate), act)
+                      * _bpm.bp_matmul_ste(x2, w_up))
+            up = up.reshape(*lead, p["up"].shape[-1]).astype(x.dtype)
     else:
         up = dense(x, p["up"], mode)
         if gated:

@@ -181,6 +181,7 @@ class DecoderModel:
             x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
         return x
 
+    @jax.named_scope("head")
     def _logits(self, params, h):
         cfg = self.cfg
         w = (params["embed"].T if cfg.tie_embeddings else params["head"])
@@ -423,6 +424,13 @@ class DecoderModel:
         return logits[:, 0], cache
 
 
+@jax.named_scope("head")
+def _tied_head(h, embed):
+    """(B, D) hidden states -> (B, V) logits over the tied embedding."""
+    return jnp.einsum("bd,vd->bv", h.astype(jnp.float32),
+                      embed.astype(jnp.float32))
+
+
 def _decode_positions(pos, b):
     """(B, 1) positions from a scalar (lock-step) or (B,) (paged) pos."""
     pos = jnp.asarray(pos)
@@ -601,8 +609,7 @@ class EncDecModel:
         h, selfc, cross = self._decode_stack(params, x, positions, enc_out,
                                              cache["self"], "prefill")
         cache = {"self": selfc, "cross": cross}
-        logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def prefill_chunk(self, params, batch, cache, pos0):
@@ -626,8 +633,7 @@ class EncDecModel:
         h, selfc, cross = self._decode_stack(params, x, positions, enc_out,
                                              cache["self"], "prefill_chunk",
                                              cross_cache=cross_cache)
-        logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, -1], params["embed"])
         return logits, {"self": selfc, "cross": cross}
 
     def decode_step(self, params, tokens, cache, pos):
@@ -644,8 +650,7 @@ class EncDecModel:
         h, selfc, cross = self._decode_stack(params, x, positions, None,
                                              cache["self"], "decode",
                                              cross_cache=cache["cross"])
-        logits = jnp.einsum("bd,vd->bv", h[:, 0].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, 0], params["embed"])
         return logits, {"self": selfc, "cross": cross}
 
 
@@ -768,8 +773,7 @@ class HybridModel:
         x = embed_lookup(params["embed"], tokens)
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
         h, cache = self._forward(params, x, positions, cache, "prefill")
-        logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def prefill_chunk(self, params, batch, cache, pos0):
@@ -783,8 +787,7 @@ class HybridModel:
         positions = jnp.broadcast_to(
             jnp.asarray(pos0) + jnp.arange(s)[None], (b, s))
         h, cache = self._forward(params, x, positions, cache, "prefill_chunk")
-        logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def decode_step(self, params, tokens, cache, pos):
@@ -792,8 +795,7 @@ class HybridModel:
         x = embed_lookup(params["embed"], tokens)
         positions = _decode_positions(pos, b)
         h, cache = self._forward(params, x, positions, cache, "decode")
-        logits = jnp.einsum("bd,vd->bv", h[:, 0].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, 0], params["embed"])
         return logits, cache
 
 
@@ -896,8 +898,7 @@ class XLSTMModel:
         cache = jax.tree.map(lambda sp: jnp.zeros(sp.shape, sp.dtype), spec)
         x = embed_lookup(params["embed"], tokens)
         h, cache = self._forward(params, x, cache, "prefill")
-        logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def prefill_chunk(self, params, batch, cache, pos0):
@@ -908,15 +909,13 @@ class XLSTMModel:
         tokens = batch["tokens"]
         x = embed_lookup(params["embed"], tokens)
         h, cache = self._forward(params, x, cache, "prefill_chunk")
-        logits = jnp.einsum("bd,vd->bv", h[:, -1].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def decode_step(self, params, tokens, cache, pos):
         x = embed_lookup(params["embed"], tokens)
         h, cache = self._forward(params, x, cache, "decode")
-        logits = jnp.einsum("bd,vd->bv", h[:, 0].astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+        logits = _tied_head(h[:, 0], params["embed"])
         return logits, cache
 
 

@@ -39,6 +39,7 @@ def moe_defs(cfg: ModelConfig, dtype=jnp.bfloat16):
     return defs
 
 
+@jax.named_scope("moe")
 def moe_apply(p, cfg: ModelConfig, x: jax.Array,
               capacity: Optional[int] = None) -> Dict[str, jax.Array]:
     """x: (B, S, d) -> {'out': (B, S, d), 'aux_loss': scalar}.

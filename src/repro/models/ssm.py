@@ -112,6 +112,7 @@ def _ssd_chunked(x, dt, a, b, c, chunk: int, h0=None, decay_bf16=False):
     return y, hfin
 
 
+@jax.named_scope("ssm")
 def mamba2_apply(p, cfg: ModelConfig, x: jax.Array,
                  state: Optional[Dict] = None, chunk: int = 256):
     """x: (B,S,D). state (decode): {'conv': (B,W-1,convdim), 'ssm': (B,H,P,N)}.
@@ -276,6 +277,7 @@ def _mlstm_chunked(q, k, v, log_i, log_f, chunk: int, state=None):
     return y.reshape(bs, s, h, d), {"C": Cf, "n": nf, "m": mf}
 
 
+@jax.named_scope("ssm")
 def mlstm_apply(p, cfg: ModelConfig, x: jax.Array, state=None,
                 chunk: int = 256):
     bs, s, d = x.shape
@@ -335,6 +337,7 @@ def slstm_defs(cfg: ModelConfig, dtype=jnp.bfloat16):
     }
 
 
+@jax.named_scope("ssm")
 def slstm_apply(p, cfg: ModelConfig, x: jax.Array, state=None):
     """Sequential sLSTM.  x: (B,S,D).  state: {'c','n','h','m'} each (B,H,hd)
     except m: (B,H)."""

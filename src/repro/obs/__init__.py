@@ -3,23 +3,30 @@
   registry.py  process-local counters/gauges/histograms with labeled
                series (snapshot / to_jsonl), plus the append-only JSONL
                step logger that absorbed ``repro.utils.metrics``
-  trace.py     span-based tracing (monotonic clocks, nesting, lanes)
-               with a Chrome-trace/Perfetto exporter, and adapters that
-               render the OISMA engine simulator's round walk and
-               tile-class traces onto the same timeline
+  trace.py     in-memory spans (``time.perf_counter`` or an injected
+               clock, nesting, lanes) with a Chrome-trace/Perfetto
+               exporter, and adapters that render the OISMA engine
+               simulator's round walk and tile-class traces onto the
+               same timeline
   watchdog.py  JAX compile/retrace watchdog: per-callsite compile-count
                bounds asserted live (the paged engine's O(log) shape
                guarantee as a running metric, not just a test)
 
 ``Observability`` is the bundle the instrumented layers accept: the
-paged serving engine, the trainer, and the benchmarks each take an
-optional ``obs`` and stay zero-overhead without one.  See
-``docs/observability.md`` for the metric catalog and span taxonomy.
+paged serving engine, the trainer and the checkpoint manager each take
+an optional ``obs`` and reach spans only through ``Observability.span``.
+That span is a ``jax.profiler.TraceAnnotation``, so while a profile is
+captured it lands in the ``.xplane.pb`` host plane on the device
+planes' clock, where device idle gaps and ops can be put down to it.
+See ``docs/observability.md`` for the metric catalog and span taxonomy.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Optional
+from typing import Any, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.registry import (JsonlLogger, MetricsRegistry, percentile,
                                 read_metrics, step_time_summary)
@@ -33,8 +40,8 @@ class Observability:
     """What an instrumented layer needs, in one handle.
 
     Any field may be None: the registry is the cheap always-on half,
-    the tracer opts into timeline capture, the watchdog opts into live
-    compile-bound assertion.
+    the tracer opts into an in-memory timeline for the Chrome/Perfetto
+    export, the watchdog opts into live compile-bound assertion.
     """
     registry: MetricsRegistry = dataclasses.field(
         default_factory=MetricsRegistry)
@@ -54,10 +61,33 @@ class Observability:
               if watchdog_limit is not None else None)
         return cls(registry=registry, tracer=tracer, watchdog=wd)
 
+    def span(self, name: str, tid: int = 0, **args: Any):
+        """A span around the ``with`` block: a profiler annotation named
+        ``name`` with ``args`` as its stats (a no-op of about a
+        microsecond when no profile is being captured), and where a
+        tracer is attached, also its in-memory span on lane ``tid``."""
+        if self.tracer is None:
+            return TraceAnnotation(name, **args)
+        return _traced_span(self.tracer, name, tid, args)
+
+
+@contextlib.contextmanager
+def _traced_span(tracer: Tracer, name: str, tid: int, args: dict):
+    with TraceAnnotation(name, **args), tracer.span(name, tid=tid, **args):
+        yield
+
+
+def no_span(name: str, tid: int = 0, **args: Any):
+    """``Observability.span`` for a layer given no ``obs``: nothing."""
+    return _NO_SPAN
+
+
+_NO_SPAN = contextlib.nullcontext()
+
 
 __all__ = [
     "JsonlLogger", "MetricsRegistry", "percentile", "read_metrics",
     "step_time_summary", "TraceEvent", "Tracer", "chrome_doc",
     "round_walk_chrome_trace", "sim_chrome_trace", "RetraceError",
-    "RetraceWatchdog", "call_signature", "Observability",
+    "RetraceWatchdog", "call_signature", "Observability", "no_span",
 ]

@@ -37,7 +37,7 @@ LabelKey = Tuple[Tuple[str, str], ...]
 
 
 def _label_key(labels: Mapping[str, Any]) -> LabelKey:
-    return tuple(sorted((k, str(v)) for k, v in labels.items()))
+    return tuple(sorted([(k, str(v)) for k, v in labels.items()]))
 
 
 def percentile(sorted_values: List[float], q: float) -> float:
@@ -91,6 +91,12 @@ class MetricsRegistry:
     def gauge(self, name: str, value: float, **labels: Any) -> None:
         """Set a point-in-time value (last write wins)."""
         self._get(name, "gauge", labels).value = float(value)
+
+    def peak(self, name: str, value: float, **labels: Any) -> None:
+        """Raise a gauge to ``value`` where that is larger: the running
+        maximum since the gauge was last set with ``gauge``."""
+        s = self._get(name, "gauge", labels)
+        s.value = max(s.value, float(value))
 
     def observe(self, name: str, value: float, **labels: Any) -> None:
         """Record one histogram observation."""

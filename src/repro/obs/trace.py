@@ -1,13 +1,18 @@
-"""Span-based tracing with a Chrome-trace/Perfetto JSON exporter.
+"""In-memory span timeline with a Chrome-trace/Perfetto JSON exporter.
 
-``Tracer`` records nested spans (``with tracer.span("prefill_chunk",
+``Tracer`` records nested spans (``with tracer.span("engine.prefill",
 rid=3):``) against an injectable monotonic clock — real runs use
 ``time.perf_counter``, tests inject a fake clock for byte-deterministic
-output.  Spans are Chrome-trace "complete" events (``ph: "X"`` with
-``ts``/``dur`` in microseconds); lanes are ``tid``s named via
-``set_thread_name``.  Because spans close through a per-lane context
-stack, events on one lane always nest properly — the well-formedness
-the exporter relies on and ``tests/test_obs.py`` pins.
+output. That clock is not the profiler's: no device time can be read
+against these spans. The instrumented layers do not call the tracer
+themselves; ``Observability.span`` writes each span as a
+``jax.profiler.TraceAnnotation`` (the profiler's clock, for device
+metrics) and, where a tracer is attached, here as well. Spans are
+Chrome-trace "complete" events (``ph: "X"`` with ``ts``/``dur`` in
+microseconds); lanes are ``tid``s named via ``set_thread_name``. Because
+spans close through a per-lane context stack, events on one lane always
+nest properly — the well-formedness the exporter relies on and
+``tests/test_obs.py`` pins.
 
 Open the exported file at https://ui.perfetto.dev (or
 ``chrome://tracing``): drag the JSON in, lanes render as threads,

@@ -30,20 +30,30 @@ keeps the traffic harness's latency numbers deterministic and
 platform-independent — see ``docs/serving.md`` for the metric
 definitions.
 
+Each request is stamped on ``time.perf_counter`` where its work
+happens (``arrival_s`` at submit, ``admitted_s``, ``first_token_s``,
+``finish_s``), beside the step counts, with or without ``obs``.
+
 Observability: pass an ``repro.obs.Observability`` to get (1) per-request
-lifecycle records (queue wait, TTFT, latency — appended to
+lifecycle records (queue wait, TTFT, latency in steps — appended to
 ``engine.lifecycle`` at retire time and the raw material every
-``BENCH_serve.json`` percentile is recomputed from), (2) spans per
-engine step / prefill chunk / decode tick on the obs tracer, (3)
-block-pool occupancy and queue-depth gauges plus admit/reject/defer
-counters on the obs registry, and (4) the retrace watchdog wrapped
-around both jitted entry points so the O(log) compile bound is asserted
-*while serving*, not just in tests.  Without ``obs`` the engine only
-keeps its cheap ``EngineStats``.
+``BENCH_serve.json`` percentile is recomputed from), (2) spans that tile
+each step (``Observability.span``, so on the profiler's clock): inside
+``engine.step``, ``engine.retire`` (``engine.scrub`` per freed slot),
+``engine.admit``, and per prefill chunk and decode tick
+``engine.gather``, ``engine.prefill`` / ``engine.decode`` (the model
+call), ``engine.commit`` and ``engine.sample``, (3) the host seconds of
+each of those phases, ``serve.phase_s{phase}``, and the longest single
+one, ``serve.phase_max_s{phase}``, block-pool occupancy and queue-depth
+gauges, and admit/reject/defer counters on the obs registry, and (4) the
+retrace watchdog wrapped around both jitted entry points so the O(log)
+compile bound is asserted *while serving*, not just in tests.  Without
+``obs`` the engine only keeps its cheap ``EngineStats``.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
@@ -51,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.obs import no_span
 from repro.serve.paged_cache import PagedCache
 from repro.serve.sampling import sample_row, sample_tokens
 from repro.serve.scheduler import PriorityScheduler
@@ -69,6 +80,11 @@ class PagedRequest:
     admitted_step: Optional[int] = None
     first_token_step: Optional[int] = None
     finish_step: Optional[int] = None
+    # the same events on time.perf_counter (filled in by the engine)
+    arrival_s: Optional[float] = None
+    admitted_s: Optional[float] = None
+    first_token_s: Optional[float] = None
+    finish_s: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -146,6 +162,28 @@ class _Slot:
         return self.pos < len(self.req.prompt)
 
 
+class _Phase:
+    """One phase of an engine step: the span ``engine.<phase>``, and with
+    a registry its host seconds, added to ``serve.phase_s{phase}`` and
+    raised into ``serve.phase_max_s{phase}``."""
+
+    __slots__ = ("registry", "phase", "span", "t0")
+
+    def __init__(self, registry, phase: str, span):
+        self.registry, self.phase, self.span = registry, phase, span
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        if self.registry is not None:
+            dt = time.perf_counter() - self.t0
+            self.registry.counter("serve.phase_s", dt, phase=self.phase)
+            self.registry.peak("serve.phase_max_s", dt, phase=self.phase)
+        self.span.__exit__(*exc)
+
+
 class PagedServeEngine:
     """model: needs prefill_chunk + decode_step (vector positions)."""
 
@@ -164,7 +202,7 @@ class PagedServeEngine:
         self._decode = jax.jit(model.decode_step)
         self._prefill_chunk = jax.jit(model.prefill_chunk)
         self.obs = obs
-        self._tracer = obs.tracer if obs is not None else None
+        self._span = obs.span if obs is not None else no_span
         self._registry = obs.registry if obs is not None else None
         if obs is not None and obs.watchdog is not None:
             limits = self.compile_shape_bounds()
@@ -213,6 +251,7 @@ class PagedServeEngine:
 
     def submit(self, req: PagedRequest) -> None:
         req.arrival_step = self.step_count
+        req.arrival_s = time.perf_counter()
         if not self.scheduler.submit(req):
             self.stats.rejected += 1
             if self._registry is not None:
@@ -224,39 +263,29 @@ class PagedServeEngine:
                 f"{self.ecfg.block_size})")
         if self._registry is not None:
             self._registry.counter("serve.submitted_requests")
-        if self._tracer is not None:
-            self._tracer.instant("submit", rid=req.rid,
-                                 prompt_tokens=int(len(req.prompt)),
-                                 priority=req.priority,
-                                 step=self.step_count)
 
     # -- engine loop ----------------------------------------------------
 
     def step(self) -> None:
         """One engine step: retire, admit, prefill one chunk per
         prefilling slot, decode one token for every decoding slot."""
-        if self._tracer is not None:
-            with self._tracer.span("engine_step", step=self.step_count):
-                self._retire()
-                self._admit()
-                self._prefill_tick()
-                self._decode_tick()
-        else:
+        with self._span("engine.step", step=self.step_count):
             self._retire()
             self._admit()
             self._prefill_tick()
             self._decode_tick()
-        self.step_count += 1
-        self.stats.steps += 1
-        if self._registry is not None:
-            used = self.ecfg.num_blocks - 1 - self.cache.free_blocks
-            self._registry.gauge("serve.blocks_in_use", used)
-            self._registry.observe("serve.blocks_in_use_per_step", used)
-            self._registry.gauge("serve.queue_depth", self.scheduler.pending)
-            self._registry.gauge("serve.live_slots", self.live)
-        if self._tracer is not None:
-            used = self.ecfg.num_blocks - 1 - self.cache.free_blocks
-            self._tracer.counter("blocks_in_use", used)
+            self.step_count += 1
+            self.stats.steps += 1
+            if self._registry is not None:
+                used = self.ecfg.num_blocks - 1 - self.cache.free_blocks
+                self._registry.gauge("serve.blocks_in_use", used)
+                self._registry.gauge("serve.queue_depth",
+                                     self.scheduler.pending)
+                self._registry.gauge("serve.live_slots", self.live)
+
+    def _phase(self, phase: str, tid: int = 0, **args) -> "_Phase":
+        return _Phase(self._registry, phase,
+                      self._span("engine." + phase, tid=tid, **args))
 
     def run(self, requests: List[PagedRequest],
             seed: Optional[int] = None) -> Dict[int, List[int]]:
@@ -279,71 +308,63 @@ class PagedServeEngine:
     # -- phases ---------------------------------------------------------
 
     def _retire(self) -> None:
-        for i, s in enumerate(self._slots):
-            if s is not None and s.req.done:
-                self.results[s.req.rid] = s.req.out_tokens
-                self.lifecycle.append(lifecycle_record(s.req))
-                if self._registry is not None:
-                    self._registry.counter("serve.completed_requests")
-                    self._registry.counter("serve.output_tokens",
-                                           len(s.req.out_tokens))
-                    rec = self.lifecycle[-1]
-                    for m in ("queue_wait_steps", "ttft_steps",
-                              "latency_steps"):
-                        self._registry.observe(f"serve.{m}", rec[m])
-                if self._tracer is not None:
-                    self._tracer.instant("retire", rid=s.req.rid, slot=i,
-                                         output_tokens=len(s.req.out_tokens))
-                self.cache.free_slot(i)
-                self._slots[i] = None
+        with self._phase("retire"):
+            for i, s in enumerate(self._slots):
+                if s is not None and s.req.done:
+                    self.results[s.req.rid] = s.req.out_tokens
+                    self.lifecycle.append(lifecycle_record(s.req))
+                    if self._registry is not None:
+                        self._registry.counter("serve.completed_requests")
+                        self._registry.counter("serve.output_tokens",
+                                               len(s.req.out_tokens))
+                    with self._span("engine.scrub", slot=i):
+                        self.cache.free_slot(i)
+                    self._slots[i] = None
 
     def _admit(self) -> None:
-        free = [i for i, s in enumerate(self._slots) if s is None]
-        admitted = self.scheduler.admit(len(free), self.cache.free_blocks)
-        for req in admitted:
-            i = free.pop(0)
-            self.cache.alloc_slot(i, self.scheduler.reservation(req))
-            req.admitted_step = self.step_count
-            self._slots[i] = _Slot(req)
-            if self._tracer is not None:
-                self._tracer.instant("admit", rid=req.rid, slot=i,
-                                     queue_wait=req.admitted_step
-                                     - req.arrival_step)
-        self.stats.admitted += len(admitted)
-        if self._registry is not None and admitted:
-            self._registry.counter("serve.admitted_requests", len(admitted))
-        # a leftover free slot with a queue behind it means the head-of-
-        # line request didn't fit the free blocks: a deferral step
-        if free and self.scheduler.pending:
-            self.stats.deferred_steps += 1
-            if self._registry is not None:
-                self._registry.counter("serve.deferred_steps")
+        with self._phase("admit"):
+            free = [i for i, s in enumerate(self._slots) if s is None]
+            admitted = self.scheduler.admit(len(free), self.cache.free_blocks)
+            now = time.perf_counter()
+            for req in admitted:
+                i = free.pop(0)
+                self.cache.alloc_slot(i, self.scheduler.reservation(req))
+                req.admitted_step = self.step_count
+                req.admitted_s = now
+                self._slots[i] = _Slot(req)
+            self.stats.admitted += len(admitted)
+            if self._registry is not None and admitted:
+                self._registry.counter("serve.admitted_requests",
+                                       len(admitted))
+            # a leftover free slot with a queue behind it means the head-of-
+            # line request didn't fit the free blocks: a deferral step
+            if free and self.scheduler.pending:
+                self.stats.deferred_steps += 1
+                if self._registry is not None:
+                    self._registry.counter("serve.deferred_steps")
 
     def _prefill_tick(self) -> None:
         for i, s in enumerate(self._slots):
             if s is None or not s.prefilling:
                 continue
-            remaining = len(s.req.prompt) - s.pos
-            chunk = min(remaining, self.ecfg.max_prefill_tokens)
-            chunk = 1 << (chunk.bit_length() - 1)      # largest 2^k <= chunk
-            view_tokens = self.cache.view_len(s.pos + chunk)
-            batch = {"tokens": jnp.asarray(
-                s.req.prompt[s.pos:s.pos + chunk][None].astype(np.int32))}
-            if self.cfg.family == "encdec" and s.pos == 0:
-                batch["frames"] = jnp.zeros(
-                    (1, self.cfg.encoder_frames, self.cfg.d_model),
-                    jnp.bfloat16)
-            view = self.cache.gather([i], view_tokens)
-            if self._tracer is not None:
-                with self._tracer.span("prefill_chunk", tid=1 + i,
-                                       rid=s.req.rid, chunk=chunk,
-                                       view=view_tokens, pos=s.pos):
-                    logits, view = self._prefill_chunk(self.params, batch,
-                                                       view, jnp.int32(s.pos))
-            else:
+            with self._phase("gather"):
+                remaining = len(s.req.prompt) - s.pos
+                chunk = min(remaining, self.ecfg.max_prefill_tokens)
+                chunk = 1 << (chunk.bit_length() - 1)  # largest 2^k <= chunk
+                view_tokens = self.cache.view_len(s.pos + chunk)
+                view = self.cache.gather([i], view_tokens)
+            with self._phase("prefill", tid=1 + i, rid=s.req.rid,
+                             chunk=chunk, view=view_tokens, pos=s.pos):
+                batch = {"tokens": jnp.asarray(
+                    s.req.prompt[s.pos:s.pos + chunk][None].astype(np.int32))}
+                if self.cfg.family == "encdec" and s.pos == 0:
+                    batch["frames"] = jnp.zeros(
+                        (1, self.cfg.encoder_frames, self.cfg.d_model),
+                        jnp.bfloat16)
                 logits, view = self._prefill_chunk(self.params, batch, view,
                                                    jnp.int32(s.pos))
-            self.cache.commit_prefill(view, i, s.pos, chunk)
+            with self._phase("commit"):
+                self.cache.commit_prefill(view, i, s.pos, chunk)
             self.stats.prefill_shapes.add(
                 (chunk, view_tokens, "frames" in batch))
             self.stats.prefill_chunks += 1
@@ -351,10 +372,11 @@ class PagedServeEngine:
                 self._registry.counter("serve.prefill_tokens", chunk)
             s.pos += chunk
             if not s.prefilling:          # prompt complete: first token
-                tok = sample_row(logits[0], seed=self.ecfg.seed,
-                                 rid=s.req.rid, step=0,
-                                 temperature=self.ecfg.temperature)
-                self._accept(s, tok)
+                with self._phase("sample"):
+                    tok = sample_row(logits[0], seed=self.ecfg.seed,
+                                     rid=s.req.rid, step=0,
+                                     temperature=self.ecfg.temperature)
+                    self._accept(s, tok)
 
     def _decode_tick(self) -> None:
         live = [(i, s) for i, s in enumerate(self._slots)
@@ -362,45 +384,44 @@ class PagedServeEngine:
         if not live:
             return
         n = self.ecfg.slots
-        slot_ids = np.zeros(n, np.int32)      # padding rows gather slot 0
-        tokens = np.zeros(n, np.int32)
-        positions = np.zeros(n, np.int32)
-        rows = []
-        for r, (i, s) in enumerate(live):
-            slot_ids[r], tokens[r], positions[r] = i, s.next_token, s.pos
-            rows.append((s.req.rid, len(s.req.out_tokens)))
-        view_tokens = self.cache.view_len(int(positions.max()) + 1)
-        view = self.cache.gather(slot_ids.tolist(), view_tokens)
-        if self._tracer is not None:
-            with self._tracer.span("decode_tick", rows=len(live),
-                                   view=view_tokens):
-                logits, view = self._decode(self.params,
-                                            jnp.asarray(tokens)[:, None],
-                                            view, jnp.asarray(positions))
-        else:
+        with self._phase("gather"):
+            slot_ids = np.zeros(n, np.int32)  # padding rows gather slot 0
+            tokens = np.zeros(n, np.int32)
+            positions = np.zeros(n, np.int32)
+            rows = []
+            for r, (i, s) in enumerate(live):
+                slot_ids[r], tokens[r], positions[r] = i, s.next_token, s.pos
+                rows.append((s.req.rid, len(s.req.out_tokens)))
+            view_tokens = self.cache.view_len(int(positions.max()) + 1)
+            view = self.cache.gather(slot_ids.tolist(), view_tokens)
+        with self._phase("decode", rows=len(live), view=view_tokens):
             logits, view = self._decode(self.params,
                                         jnp.asarray(tokens)[:, None], view,
                                         jnp.asarray(positions))
-        self.cache.commit_decode(view, list(range(len(live))),
-                                 [i for i, _ in live],
-                                 [s.pos for _, s in live])
+        with self._phase("commit"):
+            self.cache.commit_decode(view, list(range(len(live))),
+                                     [i for i, _ in live],
+                                     [s.pos for _, s in live])
         self.stats.decode_shapes.add((n, view_tokens))
         self.stats.decode_ticks += 1
         if self._registry is not None:
             self._registry.counter("serve.decode_tokens", len(live))
-        rows += [None] * (n - len(rows))
-        sampled = sample_tokens(logits, rows, seed=self.ecfg.seed,
-                                temperature=self.ecfg.temperature)
-        for r, (i, s) in enumerate(live):
-            s.pos += 1                     # the input token is now cached
-            self._accept(s, int(sampled[r]))
+        with self._phase("sample"):
+            rows += [None] * (n - len(rows))
+            sampled = sample_tokens(logits, rows, seed=self.ecfg.seed,
+                                    temperature=self.ecfg.temperature)
+            for r, (i, s) in enumerate(live):
+                s.pos += 1                 # the input token is now cached
+                self._accept(s, int(sampled[r]))
 
     def _accept(self, s: _Slot, tok: int) -> None:
         req = s.req
         if req.first_token_step is None:
             req.first_token_step = self.step_count
+            req.first_token_s = time.perf_counter()
         req.out_tokens.append(tok)
         s.next_token = tok
         if tok == self.ecfg.eos_id or len(req.out_tokens) >= req.max_new_tokens:
             req.done = True
             req.finish_step = self.step_count
+            req.finish_s = time.perf_counter()

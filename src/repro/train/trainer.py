@@ -128,9 +128,7 @@ def train(model, cfg: ModelConfig, shape: ShapeConfig,
     dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=shape.seq_len,
                       global_batch=shape.global_batch, seed=tcfg.seed)
 
-    import contextlib
-
-    from repro.obs import JsonlLogger, MetricsRegistry
+    from repro.obs import JsonlLogger, MetricsRegistry, no_span
     manager = None
     if tcfg.ckpt_dir:
         manager = CheckpointManager(
@@ -171,21 +169,20 @@ def train(model, cfg: ModelConfig, shape: ShapeConfig,
     monitor = StragglerMonitor()
     logger = JsonlLogger(tcfg.metrics_path)
     registry = obs.registry if obs is not None else MetricsRegistry()
-    tracer = obs.tracer if obs is not None else None
-    _span = (tracer.span if tracer is not None
-             else lambda *a, **kw: contextlib.nullcontext())
+    span = obs.span if obs is not None else no_span
     history = []
     try:
         for step in range(start, tcfg.total_steps):
             if injector is not None:
                 injector.maybe_fail(step)
-            batch = {k: jax.numpy.asarray(v)
-                     for k, v in batch_at(dcfg, step).items()}
+            with span("train.data", step=step + 1):
+                batch = {k: jax.numpy.asarray(v)
+                         for k, v in batch_at(dcfg, step).items()}
             # perf_counter for the duration (wall-clock is NTP-skewable and
             # can run backwards mid-step); the logger stamps the one wall
             # timestamp each record keeps for cross-host alignment
             t0 = time.perf_counter()
-            with _span("train_step", step=step + 1):
+            with span("train.step", step=step + 1):
                 state, metrics = step_fn(state, batch)
                 loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
@@ -200,13 +197,11 @@ def train(model, cfg: ModelConfig, shape: ShapeConfig,
             registry.gauge("train.loss", loss)
             if straggler:
                 registry.counter("train.straggler_events")
-                if tracer is not None:
-                    tracer.instant("straggler", step=step + 1, dt=dt)
             history.append({"step": step + 1, "loss": loss, "dt": dt})
             if on_metrics:
                 on_metrics(step + 1, metrics)
             if manager is not None and (step + 1) % tcfg.ckpt_every == 0:
-                with _span("checkpoint", step=step + 1):
+                with span("train.checkpoint", step=step + 1):
                     manager.save(step + 1,
                                  _payload(state, dcfg, step + 1, tcfg.seed),
                                  blocking=not tcfg.ckpt_async)
@@ -214,7 +209,8 @@ def train(model, cfg: ModelConfig, shape: ShapeConfig,
         if manager is not None and tcfg.total_steps > start:
             # blocking final save: the manager drains the async queue
             # first, so this can never interleave with an in-flight write
-            with _span("checkpoint", step=tcfg.total_steps, final=True):
+            with span("train.checkpoint", step=tcfg.total_steps,
+                      final=True):
                 manager.save(tcfg.total_steps,
                              _payload(state, dcfg, tcfg.total_steps,
                                       tcfg.seed),

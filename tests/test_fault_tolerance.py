@@ -259,7 +259,7 @@ def test_checkpoint_write_overlaps_training(tmp_path, small):
                          ckpt_write_throttle_s=0.3),
            obs=obs)
     spans = [e for e in obs.tracer.events if e.ph == "X"]
-    steps = [e for e in spans if e.name == "train_step"]
+    steps = [e for e in spans if e.name == "train.step"]
     writes = [e for e in spans if e.name == "ckpt.write"]
     assert steps and writes
     # at least one async write ran concurrently with a later train step

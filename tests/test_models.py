@@ -85,6 +85,30 @@ def test_decode_matches_prefill(arch, built):
                 == np.argmax(np.asarray(want), -1)).all()
 
 
+@pytest.mark.parametrize("arch, scopes", [
+    ("h2o_danube_1p8b", {"dense.bf16", "attention", "kv_write", "head"}),
+    ("minicpm3_4b", {"dense.bf16", "attention", "kv_write", "head"}),
+    ("granite_moe_1b", {"moe", "attention", "kv_write"}),
+    ("zamba2_2p7b", {"ssm", "head"}),
+    ("xlstm_1p3b", {"ssm", "head"}),
+])
+def test_decode_step_carries_name_scopes(arch, scopes, built):
+    """The compiled ``decode_step`` names its ops by model layer: the
+    ``op_name`` metadata of its HLO holds each scope."""
+    import re
+
+    cfg, model, params = built[arch]
+    b = 2
+    cache = model.cache_spec(b, 16)
+    cache = jax.tree.map(lambda sp: jnp.zeros(sp.shape, sp.dtype), cache)
+    text = jax.jit(model.decode_step).lower(
+        params, jnp.zeros((b, 1), jnp.int32), cache,
+        jnp.zeros((b,), jnp.int32)).compile().as_text()
+    found = {part for name in re.findall(r'op_name="([^"]*)"', text)
+             for part in name.split("/")}
+    assert scopes <= found, scopes - found
+
+
 @pytest.mark.parametrize("arch", ["gemma3_12b"])
 def test_local_global_pattern(arch, built):
     from repro.models.model import _layer_windows, BIG_WINDOW

@@ -284,6 +284,134 @@ def test_observability_make():
     assert isinstance(bare.registry, MetricsRegistry)
 
 
+def test_registry_peak_keeps_the_running_maximum():
+    reg = MetricsRegistry()
+    for v in (0.2, 0.5, 0.1):
+        reg.peak("serve.phase_max_s", v, phase="commit")
+    assert reg.value("serve.phase_max_s", phase="commit") == 0.5
+    reg.gauge("serve.phase_max_s", 0.0, phase="commit")     # a reset
+    reg.peak("serve.phase_max_s", 0.3, phase="commit")
+    assert reg.value("serve.phase_max_s", phase="commit") == 0.3
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _profiled_host_events(tmp_path, fn, prefix):
+    """Run ``fn`` under a CPU profile (no Python tracer) and return the
+    host events whose name starts with ``prefix``: (name, start_ns,
+    duration_ns, stats), sorted by start."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((e.name, e.start_ns, e.duration_ns,
+                            {k: v for k, v in e.stats})
+                           for e in line.events if e.name.startswith(prefix))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def test_span_writes_a_profiler_event_with_args_as_stats(tmp_path):
+    from repro.obs import no_span
+
+    def spans():
+        with Observability().span("engine.prefill", rid=3, chunk=256):
+            pass
+        with no_span("engine.nothing", rid=4):
+            pass
+
+    events = _profiled_host_events(tmp_path, spans, "engine.")
+    assert [e[0] for e in events] == ["engine.prefill"]   # bare name
+    assert events[0][3] == {"rid": 3, "chunk": 256}
+
+
+def test_span_also_records_the_attached_tracer(tmp_path):
+    obs = Observability.make(trace=True, clock=FakeClock())
+
+    def spans():
+        with obs.span("train.step", step=1):
+            with obs.span("ckpt.write", tid=9, step=1):
+                pass
+
+    events = _profiled_host_events(tmp_path, spans, ("train.", "ckpt."))
+    assert [e[0] for e in events] == ["train.step", "ckpt.write"]
+    assert obs.tracer.open_spans() == 0
+    mem = {e.name: e for e in obs.tracer.events}
+    assert mem["train.step"].args == {"step": 1}
+    assert mem["ckpt.write"].tid == 9 and mem["train.step"].tid == 0
+
+
+PHASES = ("engine.retire", "engine.admit", "engine.gather",
+          "engine.prefill", "engine.decode", "engine.commit",
+          "engine.sample")
+
+
+def test_engine_phase_spans_tile_the_step(tmp_path):
+    """A tiny paged engine under a CPU profile: every phase span lies in
+    an ``engine.step``, and the registry's per-phase host seconds add up
+    to the step spans."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import build
+    from repro.models.params import init_tree
+    from repro.serve.paged_engine import (PagedEngineConfig, PagedRequest,
+                                          PagedServeEngine)
+
+    cfg = get_config("h2o_danube_1p8b", smoke=True)
+    model = build(cfg)
+    params = init_tree(model.schema(), jax.random.key(0))
+    obs = Observability()
+    eng = PagedServeEngine(model, params, cfg, PagedEngineConfig(
+        slots=2, block_size=8, num_blocks=32, max_prefill_tokens=8), obs=obs)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(2, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 13, 9)]
+
+    def serve(rid0):
+        for k, p in enumerate(prompts):
+            eng.submit(PagedRequest(rid=rid0 + k, prompt=p,
+                                    max_new_tokens=4))
+        while eng.scheduler.pending or eng.live:
+            eng.step()
+
+    serve(0)                                 # compiles every shape
+    before = {r["labels"]["phase"]: r["value"]
+              for r in obs.registry.snapshot() if r["name"] == "serve.phase_s"}
+    events = _profiled_host_events(tmp_path, lambda: serve(10), "engine.")
+    after = {r["labels"]["phase"]: r["value"]
+             for r in obs.registry.snapshot() if r["name"] == "serve.phase_s"}
+    steps = [e for e in events if e[0] == "engine.step"]
+    phases = [e for e in events if e[0] != "engine.step"]
+    assert {e[0] for e in phases} >= set(PHASES) | {"engine.scrub"}
+    for name, start, dur, _ in phases:
+        assert any(s <= start and start + dur <= s + d
+                   for _, s, d, _ in steps), name
+    prefill = next(e for e in phases if e[0] == "engine.prefill")
+    assert set(prefill[3]) == {"rid", "chunk", "view", "pos"}
+    step_s = sum(e[2] for e in steps) * 1e-9
+    phase_s = sum(after[k] - before.get(k, 0.0) for k in after)
+    assert abs(phase_s - step_s) <= 0.1 * step_s, (phase_s, step_s)
+    assert set(after) == {p.split(".")[1] for p in PHASES}
+    for p in after:
+        assert obs.registry.value("serve.phase_max_s", phase=p) > 0
+
+
 # ---------------------------------------------------------------------------
 # simulator adapters: the timeline must agree with the closed form
 # ---------------------------------------------------------------------------

@@ -191,3 +191,20 @@ def test_paged_priority_admitted_first():
     eng.submit(hi)
     eng.drain()
     assert hi.admitted_step < lo.admitted_step
+
+
+def test_paged_request_clock_stamps_are_monotone():
+    """Every request is stamped on the host clock at submit, admit, first
+    token and finish, in that order, also when it waits for a slot."""
+    cfg = get_config("h2o_danube_1p8b", smoke=True)
+    model = build(cfg)
+    params = init_tree(model.schema(), jax.random.key(0))
+    eng = _paged(cfg, model, params, slots=2)
+    reqs = [PagedRequest(rid=i, prompt=p, max_new_tokens=3)
+            for i, p in enumerate(_prompts(7, 4, 3, 12, cfg.vocab_size))]
+    eng.run(reqs)
+    for r in reqs:
+        assert (r.arrival_s <= r.admitted_s <= r.first_token_s
+                <= r.finish_s), r
+    # two slots, four requests: the last admitted waited for a retire
+    assert max(r.admitted_s for r in reqs) >= min(r.finish_s for r in reqs)
