@@ -224,10 +224,12 @@ class PagedServeEngine:
         return sum(s is not None for s in self._slots)
 
     def compile_counts(self) -> Dict[str, int]:
-        """Distinct compiled specializations per jitted entry point."""
+        """Distinct compiled specializations per jitted entry point: the
+        model's two and the cache's four."""
         out = {}
         for name, fn in (("prefill_chunk", self._prefill_chunk),
-                         ("decode_step", self._decode)):
+                         ("decode_step", self._decode),
+                         *self.cache.programs().items()):
             size = getattr(fn, "_cache_size", None)
             out[name] = size() if callable(size) else -1
         return out
@@ -236,16 +238,23 @@ class PagedServeEngine:
         """Analytic compile-count ceiling per jitted entry point — the
         O(log) guarantee in numbers: chunk sizes are the powers of two up
         to ``max_prefill_tokens``, view lengths are power-of-two block
-        counts up to the pool, the decode batch is constant.  The
-        watchdog asserts these bounds live (a smoke harness may pin a
-        tighter empirical bound via ``RetraceWatchdog(default_limit=…)``).
+        counts up to the pool, the decode batch is constant.  The cache
+        gathers one row (prefill) or ``slots`` rows (decode) per view,
+        commits a decode per view and a prefill per chunk x view, and
+        scrubs per power-of-two bucket of a slot's block count.  The
+        watchdog asserts the model's bounds live (a smoke harness may pin
+        a tighter empirical bound via ``RetraceWatchdog(default_limit=…)``).
         """
         chunk_kinds = self.ecfg.max_prefill_tokens.bit_length()
         usable = self.ecfg.num_blocks - 1          # pool minus null block
         view_kinds = (1 << max(usable - 1, 1).bit_length()).bit_length()
         encdec = 2 if self.cfg.family == "encdec" else 1
         return {"prefill_chunk": chunk_kinds * view_kinds * encdec,
-                "decode_step": view_kinds}
+                "decode_step": view_kinds,
+                "cache_gather": 2 * view_kinds,
+                "cache_commit_decode": view_kinds,
+                "cache_commit_prefill": chunk_kinds * view_kinds,
+                "cache_scrub": view_kinds}
 
     # -- request intake -------------------------------------------------
 

@@ -127,7 +127,8 @@ def test_paged_retrace_bound():
     engine compiled one per refill length: chunk sizes are powers of two
     capped by ``max_prefill_tokens`` and view lengths are power-of-two
     block counts, so 30 distinct prompt lengths must fit in
-    (log2(max_prefill_tokens)+1) x (log2(view buckets)+1) shapes."""
+    (log2(max_prefill_tokens)+1) x (log2(view buckets)+1) shapes; the
+    cache's jitted surgery is bounded the same way."""
     cfg = get_config("h2o_danube_1p8b", smoke=True)
     model = build(cfg)
     params = init_tree(model.schema(), jax.random.key(0))
@@ -147,6 +148,16 @@ def test_paged_retrace_bound():
     if counts["prefill_chunk"] >= 0:        # _cache_size available
         assert counts["prefill_chunk"] <= chunk_kinds * view_kinds
         assert counts["decode_step"] <= view_kinds
+        # the cache's programs: a gather per (1 or slots rows) x view, one
+        # decode commit per view whatever the live rows, a prefill commit
+        # per chunk x view, a scrub per power-of-two reservation bucket
+        scrub_kinds = 3                     # 1, 2, 4 blocks reserved
+        assert counts["cache_gather"] <= 2 * view_kinds
+        assert counts["cache_commit_decode"] <= len(eng.stats.decode_shapes)
+        assert counts["cache_commit_prefill"] <= chunk_kinds * view_kinds
+        assert counts["cache_scrub"] <= scrub_kinds
+        bounds = eng.compile_shape_bounds()
+        assert all(counts[k] <= bounds[k] for k in bounds), (counts, bounds)
     assert len(eng.stats.prefill_shapes) < len(set(lengths))
 
 
