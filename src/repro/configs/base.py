@@ -24,6 +24,16 @@ class ModelConfig:
     local_global_pattern: int = 0            # N local layers per 1 global
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    # YaRN rotary scaling (MLA's rotary part; factor 0 = plain RoPE):
+    # interpolated frequencies between the beta_fast / beta_slow
+    # corrections, cos/sin scaled by mscale(mscale) / mscale(mscale_all_dim)
+    # and the softmax by mscale(mscale_all_dim)**2
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max_pos: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     logit_softcap: Optional[float] = None
     # MLA (minicpm3 / deepseek-v2)
     q_lora_rank: int = 0
@@ -41,6 +51,22 @@ class ModelConfig:
     moe_d_ff: int = 0
     first_dense_layers: int = 0
     capacity_factor: float = 1.25
+    # routing as DeepSeek-V2's config states it: softmax scores, then
+    # top-k over all experts ("greedy") or over the experts of the
+    # ``moe_topk_group`` best of ``moe_n_group`` groups, a group scored by
+    # its best expert ("group_limited_greedy"); the k weights are
+    # renormalised (``moe_norm_topk_prob``) or else scaled by
+    # ``moe_routed_scale``
+    moe_topk_method: str = "greedy"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_norm_topk_prob: bool = True
+    moe_routed_scale: float = 1.0
+    # expert share: this device holds routed experts [experts_offset,
+    # experts_offset + experts_held) of the router's num_experts and
+    # computes only their part of the layer (0 = it holds them all)
+    experts_held: int = 0
+    experts_offset: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     ssm_headdim: int = 64

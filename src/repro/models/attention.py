@@ -22,7 +22,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (ParamDef, apply_rope, dense, linear_def,
-                                 norm_def, rms_norm)
+                                 norm_def, rms_norm, yarn_frequencies,
+                                 yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -679,6 +680,20 @@ def mla_defs(cfg: ModelConfig, dtype=jnp.bfloat16):
     return defs
 
 
+def mla_rotary(cfg: ModelConfig):
+    """(frequencies or None, cos/sin scale, softmax mscale) of MLA's rotary
+    part: plain RoPE, or YaRN where ``rope_yarn_factor`` is set, whose
+    softmax scale is multiplied by mscale(mscale_all_dim) squared."""
+    f = cfg.rope_yarn_factor
+    if not f:
+        return None, 1.0, 1.0
+    freqs = yarn_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta, f,
+                             cfg.rope_yarn_original_max_pos,
+                             cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow)
+    all_dim = yarn_mscale(f, cfg.rope_yarn_mscale_all_dim)
+    return freqs, yarn_mscale(f, cfg.rope_yarn_mscale) / all_dim, all_dim
+
+
 def _mla_q(p, cfg, x, tp_attn=False):
     from repro.dist import tp as mtp
     b, s, _ = x.shape
@@ -723,10 +738,13 @@ def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
     krope = dkv[..., cfg.kv_lora_rank:]           # (B, S, rope_dim)
     q_pos = positions if positions.ndim == 2 else jnp.broadcast_to(
         positions[None], (b, sq))
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    krope = apply_rope(krope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    freqs, rot_scale, mscale = mla_rotary(cfg)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, freqs, rot_scale)
+    krope = apply_rope(krope[:, :, None, :], positions, cfg.rope_theta,
+                       freqs, rot_scale)[:, :, 0]
 
     scale = 1.0 / jnp.sqrt(jnp.float32(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    scale = scale * mscale * mscale
 
     if cache is not None and sq == 1:
         # ---- absorbed decode ----
@@ -778,6 +796,8 @@ def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
             axis=-1)
         q = jnp.concatenate([q_nope.astype(jnp.float32),
                              q_rope.astype(jnp.float32)], axis=-1)
+        if mscale != 1.0:
+            q = q * (mscale * mscale)
         out = sdpa(q, k, v, q_pos, kv_pos, causal=True, window=window,
                    chunk=cfg.attn_chunk)
     else:
@@ -805,6 +825,8 @@ def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
             axis=-1)
         q = jnp.concatenate([q_nope.astype(jnp.float32),
                              q_rope.astype(jnp.float32)], axis=-1)
+        if mscale != 1.0:
+            q = q * (mscale * mscale)
         out = sdpa(q, k, v, q_pos, q_pos, causal=True, window=window,
                    chunk=cfg.attn_chunk)
         new_cache = cache

@@ -9,6 +9,7 @@ straight-through gradient), ``'fp8'`` through the paper's E4M3 baseline,
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -95,16 +96,50 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D); positions: (B, S) or (S,)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor``-fold."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> jax.Array:
+    """YaRN's rotary frequencies (arXiv:2309.00071, as DeepSeek-V2 applies
+    them): the original frequencies where a channel turns more than
+    ``beta_fast`` times over the original context, the frequencies divided
+    by ``factor`` where it turns fewer than ``beta_slow`` times, and a
+    linear ramp between."""
+    def channel(turns):
+        return (head_dim * math.log(original_max_pos / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(channel(beta_fast)), 0)
+    high = min(math.ceil(channel(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_frequencies(head_dim, theta)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               scale: float = 1.0) -> jax.Array:
+    """x: (B, S, H, D); positions: (B, S) or (S,).  ``freqs`` (D/2,)
+    replaces the plain frequencies of ``theta`` (YaRN), and ``scale``
+    multiplies cos and sin."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                       # (D/2,)
+    if freqs is None:
+        freqs = rope_frequencies(d, theta)                   # (D/2,)
     pos = positions.astype(jnp.float32)
     if pos.ndim == 1:
         pos = pos[None, :]
     angles = pos[..., None] * freqs[None, None, :]           # (B, S, D/2)
     sin = jnp.sin(angles)[:, :, None, :]
     cos = jnp.cos(angles)[:, :, None, :]
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -77,6 +77,8 @@ def _layer_windows(cfg: ModelConfig) -> np.ndarray:
 
 def _decoder_layer_apply(p, cfg: ModelConfig, x, positions, *, window,
                          cache=None, prefix_len=None, append=False):
+    """-> (x, new cache, aux loss, the MoE layer's routes: None for a dense
+    layer or outside serving)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.attention_type == "mla":
         a, new_cache = attn.mla_apply(p["attn"], cfg, h, positions,
@@ -90,15 +92,16 @@ def _decoder_layer_apply(p, cfg: ModelConfig, x, positions, *, window,
         a = rms_norm(a, p["post_ln1"], cfg.norm_eps)
     x = x + a
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    aux = jnp.float32(0.0)
+    aux, routes = jnp.float32(0.0), None
     if "moe" in p:
-        r = moe_mod.moe_apply(p["moe"], cfg, h)
-        m, aux = r["out"], r["aux_loss"]
+        # serving (a cache) dispatches without dropping tokens
+        r = moe_mod.moe_apply(p["moe"], cfg, h, dropless=cache is not None)
+        m, aux, routes = r["out"], r["aux_loss"], r.get("routes")
     else:
         m = mlp_apply(p["mlp"], h, cfg.act, cfg.mlp_gated, cfg.matmul_mode)
     if "post_ln2" in p:
         m = rms_norm(m, p["post_ln2"], cfg.norm_eps)
-    return x + m, new_cache, aux
+    return x + m, new_cache, aux, routes
 
 
 @dataclasses.dataclass
@@ -131,6 +134,16 @@ class DecoderModel:
         n_dense = cfg.first_dense_layers
 
         def run_stack(stack_params, stack_cache, x, windows_arr, aux_total):
+            # serving's grouped expert matmuls (a custom call, which cannot
+            # fuse a slice of its operand) read each layer's experts from
+            # the stacked weights: scanning over them would copy every
+            # held expert out of the stack in every layer of every call
+            experts = None
+            if caches is not None and "moe" in stack_params:
+                moe_p = dict(stack_params["moe"])
+                experts = {k: moe_p.pop(k) for k in moe_mod.EXPERT_WEIGHTS}
+                stack_params = dict(stack_params, moe=moe_p)
+
             def layer_fn(x, lp, lcache, w):
                 return _decoder_layer_apply(lp, cfg, x, positions, window=w,
                                             cache=lcache,
@@ -142,30 +155,40 @@ class DecoderModel:
 
             def body(carry, inp):
                 x, aux = carry
-                lp, lcache, w = inp
+                lp, lcache, w = inp[:3]
+                if experts is not None:      # inp[3]: the layer's index
+                    lp = dict(lp, moe=dict(lp["moe"], **experts,
+                                           layer=inp[3]))
                 lcache = _as_cache(lcache)
                 x = shard(x, "batch", "seq", None)
-                x2, ncache, aux1 = fn(x, lp, lcache, w)
+                x2, ncache, aux1, routes = fn(x, lp, lcache, w)
                 return (x2, aux + aux1), (ncache if ncache is not None
-                                          else jnp.zeros((0,)))
+                                          else jnp.zeros((0,)), routes)
 
-            (x, aux_total), new_caches = jax.lax.scan(
-                body, (x, aux_total), (stack_params, stack_cache, windows_arr))
-            return x, new_caches, aux_total
+            xs = (stack_params, stack_cache, windows_arr)
+            if experts is not None:
+                xs += (jnp.arange(len(windows_arr)),)
+            (x, aux_total), (new_caches, routes) = jax.lax.scan(
+                body, (x, aux_total), xs)
+            return x, new_caches, aux_total, routes
 
         new_cache = {}
         if n_dense:
             wd = jnp.asarray(windows[:n_dense])
             cd = caches["dense_layers"] if caches is not None else _none_like(
                 params["dense_layers"])
-            x, nc, aux_total = run_stack(params["dense_layers"], cd, x, wd,
-                                         aux_total)
+            x, nc, aux_total, _ = run_stack(params["dense_layers"], cd, x, wd,
+                                            aux_total)
             new_cache["dense_layers"] = nc
         wr = jnp.asarray(windows[n_dense:])
         cr = caches["layers"] if caches is not None else _none_like(
             params["layers"])
-        x, nc, aux_total = run_stack(params["layers"], cr, x, wr, aux_total)
+        x, nc, aux_total, routes = run_stack(params["layers"], cr, x, wr,
+                                             aux_total)
         new_cache["layers"] = nc
+        if routes is not None and cfg.experts_held:
+            # (B, S, MoE layers, k): what the serving engine counts
+            new_cache[moe_mod.ROUTES] = jnp.moveaxis(routes, 0, 2)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return x, (new_cache if caches is not None else None), aux_total
 
@@ -270,7 +293,7 @@ class DecoderModel:
                 x, aux = carry
                 lp, w = inp
                 x = shard(x, "batch", "seq", None)
-                x2, _, a1 = dense_fn(x, lp, w)
+                x2, _, a1, _ = dense_fn(x, lp, w)
                 return (x2, aux + a1), None
 
             (x, aux_outer), _ = jax.lax.scan(
@@ -294,7 +317,7 @@ class DecoderModel:
                 # positions from the local shape: inside the shard_map the
                 # batch dim is the per-(data-shard, microbatch) slice
                 pos = jnp.broadcast_to(jnp.arange(s)[None], (x.shape[0], s))
-                x2, _, a1 = _decoder_layer_apply(
+                x2, _, a1, _ = _decoder_layer_apply(
                     lp, cfg, x, pos, window=w, cache=None,
                     prefix_len=None)
                 # padded slots are identities (residual layers), so the
@@ -398,6 +421,9 @@ class DecoderModel:
         so one compile covers every chunk of the same (C, cache_len) shape
         — the paged engine decomposes prompts into power-of-two chunks for
         an O(log) compile footprint.  Returns (last-token logits, cache).
+        With an expert share (``experts_held``) the cache also holds
+        ``moe.ROUTES``: (B, C, MoE layers, k), the held expert each routed
+        pair went to, -1 where it is not held here.
         """
         cfg = self.cfg
         assert cfg.num_prefix_tokens == 0, "chunked prefill: no prefix tokens"
@@ -413,6 +439,8 @@ class DecoderModel:
         return logits[:, 0], cache
 
     def decode_step(self, params, tokens, cache, pos):
+        """One token a row at per-row positions ``pos``; -> (logits,
+        cache), the cache with ``moe.ROUTES`` as in ``prefill_chunk``."""
         cfg = self.cfg
         x = embed_lookup(params["embed"], tokens,
                          scale=cfg.local_global_pattern > 0 or

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.models.moe import ROUTES
 from repro.obs import no_span
 from repro.serve.paged_cache import PagedCache
 from repro.serve.sampling import sample_row, sample_tokens
@@ -212,6 +213,7 @@ class PagedServeEngine:
             self._decode = obs.watchdog.watch(
                 self._decode, "decode_step", limit=limits["decode_step"])
         self._slots: List[Optional[_Slot]] = [None] * ecfg.slots
+        self._routes: List[Tuple[Any, int]] = []    # (routes, live rows)
         self.step_count = 0
         self.results: Dict[int, List[int]] = {}
         self.lifecycle: List[Dict[str, Any]] = []
@@ -372,6 +374,7 @@ class PagedServeEngine:
                         jnp.bfloat16)
                 logits, view = self._prefill_chunk(self.params, batch, view,
                                                    jnp.int32(s.pos))
+                self._keep_routes(view, 1)
             with self._phase("commit"):
                 self.cache.commit_prefill(view, i, s.pos, chunk)
             self.stats.prefill_shapes.add(
@@ -386,6 +389,7 @@ class PagedServeEngine:
                                      rid=s.req.rid, step=0,
                                      temperature=self.ecfg.temperature)
                     self._accept(s, tok)
+                    self._count_routes()
 
     def _decode_tick(self) -> None:
         live = [(i, s) for i, s in enumerate(self._slots)
@@ -407,6 +411,7 @@ class PagedServeEngine:
             logits, view = self._decode(self.params,
                                         jnp.asarray(tokens)[:, None], view,
                                         jnp.asarray(positions))
+            self._keep_routes(view, len(live))
         with self._phase("commit"):
             self.cache.commit_decode(view, list(range(len(live))),
                                      [i for i, _ in live],
@@ -422,6 +427,36 @@ class PagedServeEngine:
             for r, (i, s) in enumerate(live):
                 s.pos += 1                 # the input token is now cached
                 self._accept(s, int(sampled[r]))
+            self._count_routes()
+
+    def _keep_routes(self, view, rows: int) -> None:
+        """Take a model's MoE routes (an expert share's, ``moe.ROUTES``)
+        out of the view it returned, to be counted once the step has read
+        its tokens back: they are then ready, so counting adds no device
+        sync."""
+        routes = view.pop(ROUTES, None)
+        if routes is not None and self._registry is not None:
+            self._routes.append((routes, rows))
+
+    def _count_routes(self) -> None:
+        """Held-expert counters over the live rows of the calls kept:
+        ``serve.moe_held_assignments`` (routed pairs on a held expert),
+        ``serve.moe_held_experts_touched`` (held experts with a token,
+        per layer and call) and the peak ``serve.moe_expert_load_max``
+        (most pairs on one held expert in one layer of one call)."""
+        held = self.cfg.experts_held
+        for routes, rows in self._routes:
+            r = np.asarray(routes)[:rows]              # (rows, S, L, k)
+            layers = r.shape[2]
+            r = np.moveaxis(r, 2, 0).reshape(layers, -1)
+            at = (r + held * np.arange(layers)[:, None])[r >= 0]
+            load = np.bincount(at, minlength=layers * held)
+            self._registry.counter("serve.moe_held_assignments",
+                                   int(load.sum()))
+            self._registry.counter("serve.moe_held_experts_touched",
+                                   int((load > 0).sum()))
+            self._registry.peak("serve.moe_expert_load_max", int(load.max()))
+        self._routes.clear()
 
     def _accept(self, s: _Slot, tok: int) -> None:
         req = s.req

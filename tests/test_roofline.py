@@ -50,7 +50,7 @@ def _layer_flops_xla(cfg, batch, seq):
     x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), jnp.bfloat16)
 
     def f(p, x):
-        out, _, _ = _decoder_layer_apply(
+        out, _, _, _ = _decoder_layer_apply(
             p, cfg, x, jnp.arange(seq), window=seq + 1)
         return out
 

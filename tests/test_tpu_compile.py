@@ -92,3 +92,31 @@ def test_bp8_decode_attention_compiles(s, one_chip):
         one_chip, ((b, KV_HEADS, GROUPS, HEAD_DIM), jnp.float32),
         codes, scales, codes, scales, ((b, s), jnp.int32), ((b,), jnp.int32),
         ((), jnp.int32))
+
+
+def test_held_expert_grouped_matmuls_compile(one_chip):
+    """DeepSeek-V2's serving MoE layer at published widths, one chip's 20
+    of 160 experts in each of 6 layers: the grouped matmuls are Mosaic
+    kernels that read the layer's experts from the stacked weights, with
+    no copy of one layer's experts out of the stack."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import moe
+
+    cfg = dataclasses.replace(get_config("deepseek_v2_236b"), experts_held=20)
+    layers, rows = 6, 64
+
+    def layer(x, p, i):
+        return moe.moe_apply(dict(p, layer=i), cfg, x, dropless=True)["out"]
+
+    defs = moe.moe_defs(cfg)
+    args = [jax.ShapeDtypeStruct((rows, 1, cfg.d_model), jnp.bfloat16,
+                                 sharding=one_chip),
+            {k: jax.ShapeDtypeStruct(
+                ((layers,) if k in moe.EXPERT_WEIGHTS else ()) + d.shape,
+                d.dtype, sharding=one_chip) for k, d in defs.items()},
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "bf16[20,5120,1536]" not in text and "bf16[20,1536,5120]" not in text
