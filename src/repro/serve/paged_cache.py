@@ -11,9 +11,11 @@ so long and short requests share memory with no left-pad contiguity.
 Layout falls out of the models' ``cache_axes`` names, family-agnostic:
 
 * leaves with a ``kv_seq`` axis (k/v values, rope'd keys, MLA latents,
-  per-token positions) are stored as ``(..., num_blocks, block_size,
-  ...)`` — the batch axis becomes the physical block id, the sequence
-  axis the in-block offset;
+  per-token positions) are stored ``(num_blocks, block_size, cells)``:
+  the model's batch axis becomes the physical block id, its sequence axis
+  the offset in the block, and a token's cells under every other axis
+  (layers, heads x dim) lie flattened along the last, padded to whole
+  lanes;
 * leaves without one (SSM/xLSTM recurrent states, encoder-decoder cross
   attention) are dense per slot, exactly as in the contiguous engine.
 
@@ -32,15 +34,38 @@ The surgery is four jitted programs, each named for the device trace:
 view: live rows are padded to ``slots``), ``cache_commit_prefill`` (one
 per chunk x view) and ``cache_scrub`` (one per power-of-two bucket of a
 slot's block count).  The gather fills the view with the null block's
-init value and copies only the rows' real blocks.  The three that write
-take the pool donated, so XLA updates the changed cells in place instead
-of copying whole pool leaves.  Every shape is bucketed, so the compile
-count stays O(log max_len) regardless of traffic.
+init value and copies the windows of it that hold a row's blocks.  The
+three that write take the pool
+donated, so XLA updates the changed cells in place instead of copying
+whole pool leaves.  Every shape is bucketed, so the compile count stays
+O(log max_len) regardless of traffic.
+
+Every array keeps the layout XLA gives it by default, and the pool's
+shape and each program's slices are chosen so that it needs no relayout
+of a whole pool leaf or view.  A TPU lays an array out in tiles of its
+last two axes, lanes last, unless another order wastes less: in the
+model's shape, ``(layers, blocks, block_size, heads, dim)``, a head
+dimension of 80 (h2o-danube) put the block axis in the lanes, and every
+gather and prefill commit relaid out both whole pool leaves (1.2 GB each
+at danube's widths) to move a few blocks.  ``(num_blocks, block_size,
+cells)`` with whole lanes of cells is laid out block-major, a block one
+contiguous slab.  The views keep the model's shape, and a view leaf of
+heads x 80 has its tokens in the lanes; a program reads or writes it
+128 tokens at a lane-aligned offset, since a narrower slice at a
+dynamic lane offset makes XLA relay out the whole view first.  Padding
+costs little: k and v cells fill whole lanes at danube's and
+DeepSeek-V2's widths, positions pad (24 a token at danube's 24 layers
+to 128); the engine reports the pool's bytes as ``serve.cache_pool_bytes``.
+Layouts are not pinned (``jax.experimental.layout.Format``): JAX 0.9's
+persistent compilation cache does not keep a pinned layout, and a program
+loaded from it reports the default layout while computing in the pinned
+one.
 """
 from __future__ import annotations
 
 import collections
-from typing import List, Sequence
+import math
+from typing import List, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +73,7 @@ import numpy as np
 from jax import lax
 
 NULL_BLOCK = 0
+LANES = 128     # a TPU vector register's lanes: a pool row's unit
 
 
 def init_value(dtype) -> int:
@@ -88,140 +114,199 @@ class BlockAllocator:
             self._free.append(b)
 
 
-def _copy(dsts, srcs, axes, cells, count, starts):
-    """For ``j < count``, copy one piece of every ``src`` into its ``dst``:
-    ``cells`` = (src piece, dst piece) sizes on the index axes that start
-    at each leaf's batch axis (its entry of ``axes``), every other axis
-    whole, and ``starts(j)`` = (dst start, src start) on the index axes.
-    The dynamic slices update ``dsts`` in place and touch only the
-    pieces."""
-    if not dsts:
-        return []
-    src_cell, dst_cell = cells
+class _Leaf(NamedTuple):
+    """How one pool leaf is stored.  ``bi`` is its batch axis in the
+    model's shape: the slot of a dense leaf, the block id of a kv leaf.  A
+    dense leaf (``width`` 0) keeps the model's shape.  A kv leaf is stored
+    ``(num_blocks, block_size, width)``: a block is its tokens, a token
+    its cells under every leading axis of the model's shape (``lead``,
+    the layers) and every trailing one (``tail``, heads x dim), flattened
+    and padded to whole lanes."""
+    bi: int
+    lead: tuple
+    tail: tuple
+    width: int
 
-    def body(j, dsts):
-        d, s = starts(j)
-        out = []
-        for dst, src, bi in zip(dsts, srcs, axes):
-            k = len(src_cell)
-            tail = (0,) * (src.ndim - bi - k)
-            piece = lax.dynamic_slice(
-                src, (0,) * bi + s + tail,
-                src.shape[:bi] + src_cell + src.shape[bi + k:])
-            piece = piece.reshape(src.shape[:bi] + dst_cell
-                                  + src.shape[bi + k:])
-            out.append(lax.dynamic_update_slice(
-                dst, piece.astype(dst.dtype), (0,) * bi + d + tail))
-        return out
+    @property
+    def cells(self) -> int:
+        """One token's values in a pool row, padding left out."""
+        return math.prod(self.lead) * math.prod(self.tail)
 
-    return lax.fori_loop(0, count, body, list(dsts))
+    def to_view(self, cells):
+        """``(m, >= cells)`` pool cells as a view piece, ``lead + (1, m) +
+        tail``."""
+        m, n = cells.shape[0], len(self.lead)
+        x = cells[:, :self.cells].reshape((m,) + self.lead + self.tail)
+        return jnp.expand_dims(jnp.moveaxis(x, 0, n), n)
+
+    def to_pool(self, piece):
+        """A view piece, ``lead + (1, m) + tail``, as ``(1, m, cells)``
+        pool cells."""
+        n = len(self.lead)
+        return jnp.moveaxis(piece, (n, n + 1), (0, 1)).reshape(
+            piece.shape[n:n + 2] + (self.cells,))
+
+    def take(self, view, row, pos, n: int):
+        """``n`` tokens of view row ``row`` from ``pos`` on (a multiple of
+        ``n``) as pool cells, ``(1, n, cells)``: a one-hot sum over the
+        aligned window of 128 tokens that holds them."""
+        b, w = self.bi, min(view.shape[self.bi + 1], max(LANES, n))
+        start = pos // w * w
+        win = _piece(view, b, (row, start), (1, w)).reshape(
+            self.lead + (w // n, n) + self.tail)
+        hot = (lax.broadcasted_iota(jnp.int32, (w // n,), 0)
+               == (pos - start) // n)
+        hot = hot.reshape((1,) * b + (w // n,) + (1,) * (1 + len(self.tail)))
+        return self.to_pool(jnp.sum(jnp.where(hot, win, 0), axis=b,
+                                    keepdims=True, dtype=view.dtype))
 
 
-def _runs(blocks: Sequence[int]):
-    """``(index, first block, log2 length)`` of each piece of ``blocks``
-    when cut into runs of consecutive ids and each run into power-of-two
-    lengths, longest first."""
-    blocks = np.asarray(blocks, np.int64)
-    cuts = (np.flatnonzero(np.diff(blocks) != 1) + 1).tolist()
+def _leaves(model, slots: int, num_blocks: int, block_size: int):
+    """Each pool leaf's stored ``ShapeDtypeStruct`` and its ``_Leaf``."""
+    kv_spec = jax.tree.leaves(model.cache_spec(num_blocks, block_size))
+    dense_spec = jax.tree.leaves(model.cache_spec(slots, block_size))
+    axes = jax.tree.leaves(model.cache_axes(1, 1),
+                           is_leaf=lambda x: isinstance(x, tuple))
     out = []
-    for lo, hi in zip([0, *cuts], [*cuts, len(blocks)]):
-        while lo < hi:
-            c = (hi - lo).bit_length() - 1
-            out.append((lo, int(blocks[lo]), c))
-            lo += 1 << c
+    for ks, ds, ax in zip(kv_spec, dense_spec, axes):
+        bi = ax.index("batch")
+        if "kv_seq" not in ax:
+            out.append((ds, _Leaf(bi, (), (), 0)))
+            continue
+        assert ax.index("kv_seq") == bi + 1, ax
+        lead, tail = ks.shape[:bi], ks.shape[bi + 2:]
+        width = -(-math.prod(lead + tail) // LANES) * LANES
+        spec = jax.ShapeDtypeStruct((num_blocks, block_size, width), ks.dtype)
+        out.append((spec, _Leaf(bi, lead, tail, width)))
     return out
 
 
-def _programs(kinds, block_size):
-    """The cache's jitted programs over pool leaves of ``kinds``, one
-    ``(is_kv, batch axis)`` per leaf.  The writers take the pool donated.
+def _start(x, bi, *idx):
+    """Where a piece of ``x`` starts: ``idx`` from axis ``bi`` on, 0 on
+    every other axis."""
+    return (0,) * bi + idx + (0,) * (x.ndim - bi - len(idx))
 
-    On a TPU the pool's default layout puts the block axis in the lanes
-    and the view's puts the token axis there, so XLA's gather and scatter
-    between them relay out whole arrays first: a fixed cost that a
-    prefill chunk's hundreds of cells or a rarely freed slot amortize, and
-    a decode step's few cells do not.  So the decode commit copies one
-    cell an iteration of a loop (index arrays padded to ``slots``, a count
-    of the live rows), the gather one power-of-two run of consecutive pool
-    blocks an iteration (the allocator hands a request consecutive blocks,
-    so a table is a few runs), and the prefill commit and the scrub
-    scatter."""
-    kv = [i for i, (is_kv, _) in enumerate(kinds) if is_kv]
-    dense = [i for i, (is_kv, _) in enumerate(kinds) if not is_kv]
-    axes = [bi for _, bi in kinds]
 
-    def at(bi, *idx):
-        return (slice(None),) * bi + idx
+def _piece(x, bi, idx, size):
+    """The piece of ``x`` of ``size`` from ``idx`` on, both from axis
+    ``bi`` on; every other axis whole."""
+    return lax.dynamic_slice(x, _start(x, bi, *idx), x.shape[:bi] + size
+                             + x.shape[bi + len(size):])
 
-    def split(leaves):
-        return [leaves[i] for i in kv], [leaves[i] for i in dense]
 
-    def join(kv_leaves, dense_leaves):
-        out = [None] * len(kinds)
-        for i, x in zip(kv + dense, list(kv_leaves) + list(dense_leaves)):
-            out[i] = x
-        return out
+def _update(arrays, ids, count, write):
+    """``arrays`` with each one of ``ids`` replaced, for ``k < count`` in a
+    loop, by ``write(k, i, arrays[i])``."""
+    arrays = list(arrays)
+    if ids:
+        def body(k, xs):
+            return [write(k, i, x) for i, x in zip(ids, xs)]
 
-    def cache_gather(pool, pieces, counts, rows):
-        # pieces[c]: (view row, block of the row, pool block) where a run
-        # of 2**c consecutive pool blocks starts, counts[c] of them; the
-        # rest of the view is the null block's init value
-        n_rows = rows.shape[0]
-        n_blk = pieces[0].shape[1] // n_rows
-        kv_pool, dense_pool = split(pool)
-        kv_view = [jnp.full(x.shape[:axes[i]] + (n_rows, n_blk * block_size)
-                            + x.shape[axes[i] + 2:], init_value(x.dtype),
-                            x.dtype) for i, x in zip(kv, kv_pool)]
-        for c, (r, j, b) in enumerate(pieces):
-            n = 1 << c
-            kv_view = _copy(kv_view, kv_pool, [axes[i] for i in kv],
-                            ((n, block_size), (1, n * block_size)), counts[c],
-                            lambda k, r=r, j=j, b=b: (
-                                (r[k], j[k] * block_size), (b[k], 0)))
-        dense_view = [jnp.zeros(x.shape[:axes[i]] + (n_rows,)
-                                + x.shape[axes[i] + 1:], x.dtype)
-                      for i, x in zip(dense, dense_pool)]
-        dense_view = _copy(dense_view, dense_pool, [axes[i] for i in dense],
-                           ((1,), (1,)), n_rows,
-                           lambda k: ((k,), (rows[k],)))
-        return join(kv_view, dense_view)
+        for i, x in zip(ids, lax.fori_loop(0, count, body,
+                                           [arrays[i] for i in ids])):
+            arrays[i] = x
+    return arrays
+
+
+def _window_blocks(n_blk: int, block_size: int) -> int:
+    """Blocks in one of the gather's windows of a view ``n_blk`` blocks
+    wide: 128 tokens, a view's lanes, or the whole view if narrower."""
+    return min(n_blk, max(LANES // block_size, 1))
+
+
+def _programs(leaves: Sequence[_Leaf], block_size: int):
+    """The cache's jitted programs over pool leaves stored as ``leaves``
+    say.  The writers take the pool donated.
+
+    Each moves its pieces in a loop, each piece shaped so that XLA cuts
+    it out of the array's default layout in place (module docstring): the
+    gather one 128-token window of one view row an iteration, only the
+    windows that hold a block of the row, its pool blocks taken whole
+    along the major block axis and written at a lane-aligned offset; the decode commit one token (index arrays padded
+    to ``slots``, a count of the live rows) and the prefill commit one
+    block that the chunk touches, whole, its tokens outside the chunk
+    written back as the pool holds them, each read out of the view through
+    a 128-token window (``_Leaf.take``); the scrub one freed block."""
+    kv = [i for i, leaf in enumerate(leaves) if leaf.width]
+    dense = [i for i, leaf in enumerate(leaves) if not leaf.width]
+    bi = [leaf.bi for leaf in leaves]
+    bs = block_size
+
+    def cache_gather(pool, table, wins, count, rows):
+        # table: each row's pool blocks, padded with the null block;
+        # wins[:, :count]: (row, window) of each window that holds one of
+        # the row's blocks; the rest of the view is the null block's
+        n_rows, n_blk = table.shape
+        width = _window_blocks(n_blk, bs)
+        view = [jnp.full(leaf.lead + (n_rows, n_blk * bs) + leaf.tail,
+                         init_value(x.dtype), x.dtype) if leaf.width else
+                jnp.zeros(x.shape[:leaf.bi] + (n_rows,)
+                          + x.shape[leaf.bi + 1:], x.dtype)
+                for x, leaf in zip(pool, leaves)]
+
+        def window(k, i, v):
+            r, w = wins[0, k], wins[1, k]
+            x, leaf = pool[i], leaves[i]
+            cells = jnp.take(x, lax.dynamic_slice_in_dim(
+                table[r], w * width, width), axis=0, mode="clip")
+            return lax.dynamic_update_slice(
+                v, leaf.to_view(cells.reshape(-1, x.shape[-1])),
+                _start(v, leaf.bi, r, w * width * bs))
+
+        view = _update(view, kv, count, window)
+        return _update(view, dense, n_rows, lambda k, i, v: (
+            lax.dynamic_update_slice(v, _piece(pool[i], bi[i], (rows[k],),
+                                               (1,)), _start(v, bi[i], k))))
 
     def cache_commit_decode(pool, view, kv_idx, dense_idx, count):
         # per live row: (view row, position, pool block, offset) and
         # (view row, slot); ``count`` rows are live
         vrow, vpos, block, off = kv_idx
         drow, slot = dense_idx
-        (kv_pool, dense_pool), (kv_view, dense_view) = split(pool), split(view)
-        kv_pool = _copy(kv_pool, kv_view, [axes[i] for i in kv],
-                        ((1, 1), (1, 1)), count,
-                        lambda k: ((block[k], off[k]), (vrow[k], vpos[k])))
-        dense_pool = _copy(dense_pool, dense_view, [axes[i] for i in dense],
-                           ((1,), (1,)), count,
-                           lambda k: ((slot[k],), (drow[k],)))
-        return join(kv_pool, dense_pool)
+        pool = _update(pool, kv, count, lambda k, i, x: (
+            lax.dynamic_update_slice(
+                x, leaves[i].take(view[i], vrow[k], vpos[k], 1)
+                .astype(x.dtype), (block[k], off[k], 0))))
+        return _update(pool, dense, count, lambda k, i, x: (
+            lax.dynamic_update_slice(
+                x, _piece(view[i], bi[i], (drow[k],), (1,)).astype(x.dtype),
+                _start(x, bi[i], slot[k]))))
 
-    def cache_commit_prefill(pool, view, kv_idx, slot):
-        # per cell: (position, pool block, offset); view row 0
-        vpos, block, off = kv_idx
-        out = []
-        for x, v, (is_kv, bi) in zip(pool, view, kinds):
-            if is_kv:
-                cells, idx = v[at(bi, 0, vpos)], at(bi, block, off)
-            else:
-                cells, idx = v[at(bi, 0)], at(bi, slot)
-            out.append(x.at[idx].set(cells.astype(x.dtype)))
-        return out
+    def cache_commit_prefill(pool, view, blocks, count, lo, hi, slot):
+        # blocks: (position, pool block) of each block that tokens [lo, hi)
+        # fall in, count of them, in view row 0: each is written whole,
+        # its tokens outside [lo, hi) as the pool holds them
+        p, b = blocks
 
-    def cache_scrub(pool, blocks, slot):
-        # blocks padded with ``num_blocks``, past the pool: dropped
-        out = []
-        for x, (is_kv, bi) in zip(pool, kinds):
-            if is_kv and x.dtype == jnp.int32:  # kv positions: empty again
-                x = x.at[at(bi, blocks)].set(-1, mode="drop")
-            elif not is_kv:
-                x = x.at[at(bi, slot)].set(init_value(x.dtype))
-            out.append(x)
-        return out
+        def write_block(k, i, x):
+            leaf = leaves[i]
+            new = leaf.take(view[i], 0, p[k], bs)
+            old = lax.dynamic_index_in_dim(x, b[k], 0)
+            # whole rows, padding too: a narrower piece of a padded row
+            # made XLA relay out the whole leaf (DeepSeek-V2's positions)
+            new = jnp.concatenate([new.astype(x.dtype),
+                                   old[..., leaf.cells:]], axis=-1)
+            token = p[k] + lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1)
+            return lax.dynamic_update_slice(
+                x, jnp.where((token >= lo) & (token < hi), new, old),
+                (b[k], 0, 0))
+
+        pool = _update(pool, kv, count, write_block)
+        return [lax.dynamic_update_slice(x, view[i].astype(x.dtype),
+                                         _start(x, bi[i], slot))
+                if i in dense else x for i, x in enumerate(pool)]
+
+    def cache_scrub(pool, blocks, count, slot):
+        # blocks[:count]: the freed blocks, whose positions go back to -1;
+        # the slot's dense rows go back to their init value
+        pos = [i for i in kv if pool[i].dtype == jnp.int32]
+        pool = _update(pool, pos, count, lambda k, i, x: (
+            lax.dynamic_update_slice(x, jnp.full((1,) + x.shape[1:], -1,
+                                                 x.dtype), (blocks[k], 0, 0))))
+        return [lax.dynamic_update_slice(
+            x, jnp.full(x.shape[:bi[i]] + (1,) + x.shape[bi[i] + 1:],
+                        init_value(x.dtype), x.dtype), _start(x, bi[i], slot))
+                if i in dense else x for i, x in enumerate(pool)]
 
     return (jax.jit(cache_gather),
             jax.jit(cache_commit_decode, donate_argnums=0),
@@ -242,28 +327,21 @@ class PagedCache:
         self.allocator = BlockAllocator(num_blocks)
         self.tables: List[List[int]] = [[] for _ in range(slots)]
 
-        # one spec per leaf kind: kv leaves indexed by (block, offset),
-        # dense leaves by slot row
-        kv_spec = jax.tree.leaves(model.cache_spec(num_blocks, block_size))
-        dense_spec = jax.tree.leaves(model.cache_spec(slots, block_size))
         axes = model.cache_axes(1, 1)
         self._treedef = jax.tree.structure(axes,
                                            is_leaf=lambda x: isinstance(x, tuple))
         self._axes = jax.tree.leaves(axes,
                                      is_leaf=lambda x: isinstance(x, tuple))
-        self._pool: List[jnp.ndarray] = []
-        kinds = []
-        for ks, ds, ax in zip(kv_spec, dense_spec, self._axes):
-            bi = ax.index("batch")
-            is_kv = "kv_seq" in ax
-            if is_kv:
-                assert ax.index("kv_seq") == bi + 1, ax
-            sp = ks if is_kv else ds
-            self._pool.append(jnp.full(sp.shape, init_value(sp.dtype),
-                                       sp.dtype))
-            kinds.append((is_kv, bi))
+        specs, leaves = zip(*_leaves(model, slots, num_blocks, block_size))
+        self._pool: List[jnp.ndarray] = [
+            jnp.full(sp.shape, init_value(sp.dtype), sp.dtype) for sp in specs]
         (self._gather, self._commit_decode, self._commit_prefill,
-         self._scrub) = _programs(tuple(kinds), block_size)
+         self._scrub) = _programs(leaves, block_size)
+
+    @property
+    def pool_bytes(self) -> int:
+        """The pool's bytes on the device, padding included."""
+        return sum(x.on_device_size_in_bytes() for x in self._pool)
 
     def programs(self):
         """The jitted cache programs by name (for compile counts)."""
@@ -292,10 +370,10 @@ class PagedCache:
         """
         blocks = self.tables[slot]
         self.tables[slot] = []
-        padded = np.full(round_up_pow2(len(blocks)), self.num_blocks,
-                         np.int32)
+        padded = np.zeros(round_up_pow2(len(blocks)), np.int32)
         padded[:len(blocks)] = blocks
-        self._pool = self._scrub(self._pool, padded, np.int32(slot))
+        self._pool = self._scrub(self._pool, padded, np.int32(len(blocks)),
+                                 np.int32(slot))
         self.allocator.free(blocks)
 
     # -- gather / commit -----------------------------------------------
@@ -313,26 +391,32 @@ class PagedCache:
         Rows may repeat (padding rows reuse a live slot id for the dense
         leaves; their writes are simply never committed)."""
         nb = view_tokens // self.block_size
-        n = len(slot_ids)
-        pieces = [np.zeros((3, n * (nb >> c)), np.int32)
-                  for c in range(nb.bit_length())]
-        counts = np.zeros(len(pieces), np.int32)
+        width = _window_blocks(nb, self.block_size)
+        table = np.full((len(slot_ids), nb), NULL_BLOCK, np.int32)
+        wins = np.zeros((2, len(slot_ids) * (nb // width)), np.int32)
+        count = 0
         for r, s in enumerate(slot_ids):
-            for j, b, c in _runs(self.tables[s][:nb]):
-                pieces[c][:, counts[c]] = r, j, b
-                counts[c] += 1
-        view = self._gather(self._pool, pieces, counts,
+            row = self.tables[s][:nb]
+            table[r, :len(row)] = row
+            n = -(-len(row) // width)
+            wins[0, count:count + n], wins[1, count:count + n] = r, range(n)
+            count += n
+        view = self._gather(self._pool, table, wins, np.int32(count),
                             np.asarray(slot_ids, np.int32))
         return jax.tree.unflatten(self._treedef, view)
 
     def commit_prefill(self, view, slot: int, pos0: int, chunk: int) -> None:
         """Write a slot's prefilled cells ``[pos0, pos0+chunk)`` — plus
         its dense row — from a gathered batch-1 view back to the pool."""
-        pos = np.arange(pos0, pos0 + chunk, dtype=np.int32)
-        blocks = np.asarray(self.tables[slot], np.int32)[pos // self.block_size]
+        bs, table = self.block_size, self.tables[slot]
+        first, last = pos0 // bs, (pos0 + chunk - 1) // bs
+        blocks = np.zeros((2, chunk // bs + 2), np.int32)
+        blocks[0, :last - first + 1] = np.arange(first, last + 1) * bs
+        blocks[1, :last - first + 1] = table[first:last + 1]
         self._pool = self._commit_prefill(
-            self._pool, jax.tree.leaves(view),
-            np.stack([pos, blocks, pos % self.block_size]), np.int32(slot))
+            self._pool, jax.tree.leaves(view), blocks,
+            np.int32(last - first + 1), np.int32(pos0), np.int32(pos0 + chunk),
+            np.int32(slot))
 
     def commit_decode(self, view, rows: Sequence[int],
                       slot_ids: Sequence[int],

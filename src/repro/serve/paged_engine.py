@@ -45,7 +45,8 @@ each step (``Observability.span``, so on the profiler's clock): inside
 call), ``engine.commit`` and ``engine.sample``, (3) the host seconds of
 each of those phases, ``serve.phase_s{phase}``, and the longest single
 one, ``serve.phase_max_s{phase}``, block-pool occupancy and queue-depth
-gauges, and admit/reject/defer counters on the obs registry, and (4) the
+gauges, the pool's bytes on the device (``serve.cache_pool_bytes``, set
+once), and admit/reject/defer counters on the obs registry, and (4) the
 retrace watchdog wrapped around both jitted entry points so the O(log)
 compile bound is asserted *while serving*, not just in tests.  Without
 ``obs`` the engine only keeps its cheap ``EngineStats``.
@@ -205,6 +206,9 @@ class PagedServeEngine:
         self.obs = obs
         self._span = obs.span if obs is not None else no_span
         self._registry = obs.registry if obs is not None else None
+        if self._registry is not None:
+            self._registry.gauge("serve.cache_pool_bytes",
+                                 self.cache.pool_bytes)
         if obs is not None and obs.watchdog is not None:
             limits = self.compile_shape_bounds()
             self._prefill_chunk = obs.watchdog.watch(

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.serve.paged_cache import NULL_BLOCK, PagedCache, init_value
+from repro.serve.paged_cache import LANES, NULL_BLOCK, PagedCache, init_value
 
 SLOTS, NUM_BLOCKS, BS = 4, 12, 4
 RESERVED = {0: 3, 1: 1, 2: 4}                       # slot 3 stays empty
@@ -53,24 +53,24 @@ def _random(shape, dtype, rng):
     return rng.standard_normal(shape).astype(dtype)
 
 
-def _cache(seed=0, churn=True):
-    """A cache with random pool contents and slots 0-2 allocated; the
-    null block holds every kv leaf's init value (-1 positions, zeros), as
-    the pool keeps it."""
-    cache = PagedCache(_Model(), _Cfg(), slots=SLOTS, num_blocks=NUM_BLOCKS,
+def _cache(seed=0, churn=True, num_blocks=NUM_BLOCKS, reserved=RESERVED):
+    """A cache with random pool contents and the slots of ``reserved``
+    allocated; the null block holds every kv leaf's init value (-1
+    positions, zeros), as the pool keeps it."""
+    cache = PagedCache(_Model(), _Cfg(), slots=SLOTS, num_blocks=num_blocks,
                        block_size=BS)
     rng = np.random.default_rng(seed)
     pool = []
     for leaf, ax in zip(cache._pool, cache._axes):
         a = _random(leaf.shape, leaf.dtype, rng)
         if "kv_seq" in ax:
-            a[_at(ax.index("batch"), NULL_BLOCK)] = init_value(leaf.dtype)
+            a[NULL_BLOCK] = init_value(leaf.dtype)
         pool.append(jnp.asarray(a))
     cache._pool = pool
     if churn:       # tables scattered over the pool, else runs of blocks
         cache.allocator.free(rng.permutation(
-            cache.allocator.alloc(NUM_BLOCKS - 1)).tolist())
-    for slot, n in RESERVED.items():
+            cache.allocator.alloc(num_blocks - 1)).tolist())
+    for slot, n in reserved.items():
         cache.alloc_slot(slot, n)
     return cache
 
@@ -86,6 +86,24 @@ def _np(tree):
 
 def _at(bi, *idx):
     return (slice(None),) * bi + idx
+
+
+def _cells(cache):
+    """The pool in the model's shape: a kv leaf is stored ``(blocks,
+    block_size, lanes)``, each token's cells under every layer flattened
+    along its row and padded (the padding is never read)."""
+    out = []
+    for x, spec, (is_kv, bi) in zip(
+            _np(cache._pool),
+            jax.tree.leaves(_Model().cache_spec(cache.num_blocks, BS)),
+            _kinds(cache)):
+        if is_kv:
+            lead, tail = spec.shape[:bi], spec.shape[bi + 2:]
+            x = x[..., :np.prod(lead + tail, dtype=int)].reshape(
+                (cache.num_blocks, BS) + lead + tail)
+            x = np.moveaxis(x, (0, 1), (bi, bi + 1))
+        out.append(x)
+    return out
 
 
 # -- the eager semantics, in numpy ---------------------------------------
@@ -140,7 +158,7 @@ def _random_view(cache, slot_ids, view, seed):
 
 
 def _assert_pool(cache, want):
-    got = _np(cache._pool)
+    got = _cells(cache)
     assert len(got) == len(want)
     for g, w in zip(got, want):
         assert g.dtype == w.dtype and g.shape == w.shape
@@ -152,7 +170,7 @@ def _null_block_is_untouched(cache):
     writes it."""
     for leaf, (is_kv, bi) in zip(cache._pool, _kinds(cache)):
         if is_kv:
-            assert (np.array(leaf)[_at(bi, NULL_BLOCK)]
+            assert (np.array(leaf)[NULL_BLOCK]
                     == init_value(leaf.dtype)).all()
 
 
@@ -165,11 +183,28 @@ def _null_block_is_untouched(cache):
     ([1, 0, 0, 0], 4)])                             # padding reuses slot 0
 def test_gather_matches_eager(slot_ids, view, churn):
     cache = _cache(churn=churn)
-    want = _ref_gather(cache, _np(cache._pool), slot_ids, view)
+    want = _ref_gather(cache, _cells(cache), slot_ids, view)
     got = _np(cache.gather(slot_ids, view))
     for g, w in zip(got, want):
         assert g.dtype == w.dtype
         np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("churn", [True, False])
+def test_gather_matches_eager_across_windows(churn):
+    """Views wider than one of the gather's 128-token windows: a row that
+    fills one window and part of the next, one that ends inside its first,
+    an empty one, and windows past a row's end, which hold the null
+    block."""
+    cache = _cache(40 + churn, churn, num_blocks=90,
+                   reserved={0: 45, 1: 3, 3: 33})
+    for slot_ids, view in [([0, 1, 2, 3], 256), ([3, 0], 512),
+                           ([1], 256)]:
+        want = _ref_gather(cache, _cells(cache), slot_ids, view)
+        got = _np(cache.gather(slot_ids, view))
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(g, w)
 
 
 @pytest.mark.parametrize("live", range(1, SLOTS + 1))
@@ -181,7 +216,7 @@ def test_commit_decode_matches_eager(live):
     positions = [13, 9, 2, 5][:live]
     rows = list(range(live))
     view = _random_view(cache, slot_ids + [0] * (SLOTS - live), 16, live)
-    before, view_before = _np(cache._pool), _np(view)
+    before, view_before = _cells(cache), _np(view)
     old = list(cache._pool)
     cache.commit_decode(view, rows, slot_ids, positions)
     _assert_pool(cache, _ref_write(
@@ -194,11 +229,14 @@ def test_commit_decode_matches_eager(live):
 
 
 @pytest.mark.parametrize("slot,pos0,chunk,view", [
-    (0, 0, 8, 8), (0, 8, 4, 16), (2, 3, 1, 4), (2, 4, 8, 16), (1, 0, 2, 4)])
+    (0, 0, 8, 8), (0, 8, 4, 16), (2, 3, 1, 4), (2, 4, 8, 16), (1, 0, 2, 4),
+    # chunks that start or end inside a block, which is written whole: its
+    # cells outside the chunk come back as the pool held them
+    (0, 2, 8, 16), (2, 1, 13, 16), (2, 5, 2, 8), (1, 1, 3, 4)])
 def test_commit_prefill_matches_eager(slot, pos0, chunk, view):
     cache = _cache(slot)
     v = _random_view(cache, [slot], view, pos0 + chunk)
-    before, view_before = _np(cache._pool), _np(v)
+    before, view_before = _cells(cache), _np(v)
     cache.commit_prefill(v, slot, pos0, chunk)
     _assert_pool(cache, _ref_write(
         cache, before, view_before,
@@ -214,10 +252,35 @@ def test_free_slot_matches_eager(slot):
     init state; nothing else moves, whatever the padded bucket."""
     cache = _cache(10 + slot)
     blocks = list(cache.tables[slot])
-    before = _np(cache._pool)
+    before = _cells(cache)
     free = cache.free_blocks
     cache.free_slot(slot)
     _assert_pool(cache, _ref_free(cache, before, slot, blocks))
     assert cache.tables[slot] == []
     assert cache.free_blocks == free + len(blocks)
     _null_block_is_untouched(cache)
+
+
+def test_pool_keeps_its_block_major_layout():
+    """Every kv pool leaf is made ``(blocks, block_size, whole lanes)``,
+    the shape a TPU lays out block-major (``tests/test_tpu_compile.py``
+    compiles the programs there), and every program hands the pool back
+    in the same shapes and dtypes."""
+    cache = _cache(30, churn=False)
+    for x, (is_kv, bi) in zip(cache._pool, _kinds(cache)):
+        if is_kv:
+            assert x.shape[:2] == (NUM_BLOCKS, BS)
+            assert x.shape[2] % LANES == 0
+    want = [(x.shape, x.dtype) for x in cache._pool]
+
+    def kept():
+        assert [(x.shape, x.dtype) for x in cache._pool] == want
+
+    view = cache.gather([0, 1, 2, 0], 16)
+    cache.commit_decode(view, [0, 1], [0, 1], [5, 3])
+    kept()
+    view = cache.gather([2], 16)
+    cache.commit_prefill(view, 2, 0, 8)
+    kept()
+    cache.free_slot(2)
+    kept()
