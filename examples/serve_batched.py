@@ -1,5 +1,6 @@
-"""Batched serving demo: the continuous-batching engine over a small model,
-greedy decode with prefill + per-token decode_step (KV caches / SSM states).
+"""Batched serving demo: the paged continuous-batching engine over a small
+model, greedy decode with chunked prefill + per-token decode_step (KV
+caches / SSM states).
 
 Run: PYTHONPATH=src python examples/serve_batched.py --arch zamba2_2p7b
 """
@@ -12,7 +13,8 @@ import numpy as np
 from repro.configs import get_config
 from repro.models import build
 from repro.models.params import init_tree
-from repro.serve.engine import EngineConfig, Request, ServeEngine
+from repro.serve.paged_engine import (PagedEngineConfig, PagedRequest,
+                                      PagedServeEngine)
 
 
 def main():
@@ -25,14 +27,14 @@ def main():
     cfg = get_config(args.arch, smoke=True)
     model = build(cfg)
     params = init_tree(model.schema(), jax.random.key(0))
-    engine = ServeEngine(model, params, cfg,
-                         EngineConfig(slots=4, max_len=64))
+    engine = PagedServeEngine(model, params, cfg,
+                              PagedEngineConfig(slots=4, num_blocks=32))
 
     rng = np.random.default_rng(7)
-    reqs = [Request(rid=i,
-                    prompt=rng.integers(3, cfg.vocab_size, 4 + i % 3)
-                    .astype(np.int32),
-                    max_new_tokens=args.max_new)
+    reqs = [PagedRequest(rid=i,
+                         prompt=rng.integers(3, cfg.vocab_size, 4 + i % 3)
+                         .astype(np.int32),
+                         max_new_tokens=args.max_new)
             for i in range(args.requests)]
     t0 = time.time()
     results = engine.run(reqs)

@@ -101,11 +101,6 @@ class ModelConfig:
     remat: bool = True
     scan_layers: bool = True
     attn_chunk: int = 1024       # KV chunk for memory-efficient attention
-    # ring-buffer KV caches: keep only `window_size` slots per layer.
-    # valid only for uniform-SWA archs (every layer windowed); slots are
-    # addressed pos % window with explicit position masks, so decode is
-    # exact (tests/test_models.py::test_ring_cache_decode).
-    ring_cache: bool = False
 
     @property
     def sub_quadratic(self) -> bool:

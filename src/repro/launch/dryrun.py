@@ -246,7 +246,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
             acache = model.cache_spec(shape.global_batch, cache_len)
             caxes = model.cache_axes(shape.global_batch, cache_len)
             cache_sh = shd.tree_shardings(mesh, rules, acache, caxes)
-            apos = jax.ShapeDtypeStruct((), jnp.int32)
+            apos = jax.ShapeDtypeStruct((shape.global_batch,), jnp.int32)
             jitted = jax.jit(model.decode_step,
                              in_shardings=(params_sh, batch_sh["tokens"],
                                            cache_sh, None),

@@ -7,9 +7,10 @@ Two execution paths share one mask/online-softmax core:
                online softmax, so prefill at 32k/500k never materialises a
                quadratic score tensor (flash-attention structure in jnp).
 
-KV caches carry explicit per-slot position arrays, which uniformly supports
-full-length caches and ring-buffer caches for sliding-window layers (local
-layers of gemma3 keep only `window` slots — see DESIGN.md §Long-context).
+KV caches carry explicit per-slot position arrays (-1 = empty), so masks
+come from absolute positions.  With a cache, a layer has two modes, which
+the shapes decide: one token a row (Sq == 1) is written at its row's
+position, and a longer chunk is appended at its first position.
 """
 from __future__ import annotations
 
@@ -365,34 +366,28 @@ def _kv_quantized(cfg: ModelConfig) -> bool:
     return True
 
 
-def kv_cache_spec(cfg: ModelConfig, batch: int, length: int,
-                  ring: bool = False) -> Dict[str, jax.ShapeDtypeStruct]:
-    """Abstract cache for ONE attention layer."""
-    n = min(length, cfg.window_size) if (ring and cfg.window_size) else length
+def kv_cache_spec(cfg: ModelConfig, batch: int,
+                  length: int) -> Dict[str, jax.ShapeDtypeStruct]:
+    """Abstract cache of ``length`` tokens for ONE attention layer."""
     quant = _kv_quantized(cfg)      # raises for mla + kv_quant='bp8'
+    bl, sds = (batch, length), jax.ShapeDtypeStruct
     if cfg.attention_type == "mla":
-        return {
-            "ckv": jax.ShapeDtypeStruct((batch, n, cfg.kv_lora_rank), jnp.bfloat16),
-            "krope": jax.ShapeDtypeStruct((batch, n, cfg.qk_rope_head_dim), jnp.bfloat16),
-            "pos": jax.ShapeDtypeStruct((batch, n), jnp.int32),
-        }
+        return {"ckv": sds(bl + (cfg.kv_lora_rank,), jnp.bfloat16),
+                "krope": sds(bl + (cfg.qk_rope_head_dim,), jnp.bfloat16),
+                "pos": sds(bl, jnp.int32)}
     kh, d = cfg.num_kv_heads, cfg.head_dim
     if quant:
         # int8 sign*level codes + one f32 scale per (token, kv-head): the
         # finest per-block granularity, so appends/writes never re-encode
         # neighbours and the scale pages with its tokens (same kv_seq axis)
-        return {
-            "k_codes": jax.ShapeDtypeStruct((batch, n, kh, d), jnp.int8),
-            "k_scale": jax.ShapeDtypeStruct((batch, n, kh), jnp.float32),
-            "v_codes": jax.ShapeDtypeStruct((batch, n, kh, d), jnp.int8),
-            "v_scale": jax.ShapeDtypeStruct((batch, n, kh), jnp.float32),
-            "pos": jax.ShapeDtypeStruct((batch, n), jnp.int32),
-        }
-    return {
-        "k": jax.ShapeDtypeStruct((batch, n, kh, d), jnp.bfloat16),
-        "v": jax.ShapeDtypeStruct((batch, n, kh, d), jnp.bfloat16),
-        "pos": jax.ShapeDtypeStruct((batch, n, ), jnp.int32),
-    }
+        return {"k_codes": sds(bl + (kh, d), jnp.int8),
+                "k_scale": sds(bl + (kh,), jnp.float32),
+                "v_codes": sds(bl + (kh, d), jnp.int8),
+                "v_scale": sds(bl + (kh,), jnp.float32),
+                "pos": sds(bl, jnp.int32)}
+    return {"k": sds(bl + (kh, d), jnp.bfloat16),
+            "v": sds(bl + (kh, d), jnp.bfloat16),
+            "pos": sds(bl, jnp.int32)}
 
 
 def kv_cache_axes(cfg: ModelConfig, prefix: Tuple = ("stack",)) -> Dict[str, Tuple]:
@@ -416,42 +411,17 @@ def kv_cache_axes(cfg: ModelConfig, prefix: Tuple = ("stack",)) -> Dict[str, Tup
             "pos": ax("kv_seq")}
 
 
-def init_cache(spec) -> Dict[str, jax.Array]:
-    return {k: (jnp.full(v.shape, -1, v.dtype) if k == "pos"
-                else jnp.zeros(v.shape, v.dtype)) for k, v in spec.items()}
-
-
 @jax.named_scope("kv_write")
 def _cache_write(cache: Dict[str, jax.Array], updates: Dict[str, jax.Array],
                  pos: jax.Array) -> Dict[str, jax.Array]:
-    """Write one token (Sq=1) at absolute position ``pos``.
-
-    ``pos`` is a scalar int32 (every row writes the same slot: lock-step
-    decode over a left-padded batch) or a (B,) vector (per-row positions:
-    the paged engine decodes requests at independent depths).
-    Ring semantics either way: slot = pos % cache_len (== pos for full
-    caches).
-    """
-    n = cache["pos"].shape[1]
-    b = cache["pos"].shape[0]
-    pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        slot = pos % n
-        new = {}
-        for key, val in updates.items():
-            new[key] = jax.lax.dynamic_update_slice_in_dim(
-                cache[key], val.astype(cache[key].dtype), slot, axis=1)
-        new["pos"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["pos"], jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32),
-            slot, axis=1)
-        return new
-    rows = jnp.arange(b)
-    slot = pos % n
+    """Write one token (Sq=1) a row at the rows' (B,) positions ``pos``
+    (requests decode at independent depths)."""
+    rows = jnp.arange(cache["pos"].shape[0])
     new = {}
     for key, val in updates.items():
-        new[key] = cache[key].at[rows, slot].set(
+        new[key] = cache[key].at[rows, pos].set(
             val[:, 0].astype(cache[key].dtype))
-    new["pos"] = cache["pos"].at[rows, slot].set(pos.astype(jnp.int32))
+    new["pos"] = cache["pos"].at[rows, pos].set(pos.astype(jnp.int32))
     return new
 
 
@@ -463,7 +433,7 @@ def _cache_append(cache: Dict[str, jax.Array], updates: Dict[str, jax.Array],
     ``q_pos`` is the (B, Sq) position array of the chunk; rows share the
     same contiguous span, so slot addressing comes from row 0.  The caller
     guarantees p0 + Sq <= cache length (``dynamic_update_slice`` silently
-    clamps out-of-range starts).  Not valid for ring caches.
+    clamps out-of-range starts).
     """
     p0 = q_pos[0, 0]
     new = {}
@@ -501,12 +471,13 @@ def gqa_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
               window: Optional[int], cache: Optional[Dict] = None,
               prefix_len: Optional[jax.Array] = None,
               cross_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
-              causal: bool = True, rope: bool = True, append: bool = False):
+              causal: bool = True, rope: bool = True):
     """Returns (out, new_cache).  Modes:
-       * train/prefill: cache is None or written densely
-       * decode: x is (B, 1, D); cache holds the past
-       * chunked prefill (``append``): the Sq tokens are appended into the
-         cache at slots [p0, p0+Sq) and attend over the WHOLE cache, so a
+       * train: no cache
+       * decode: x is (B, 1, D); each row's token is written at its
+         position and attends over the cache
+       * chunked prefill (Sq > 1): the chunk is appended into the cache
+         at slots [p0, p0+Sq) and attends over the WHOLE cache, so a
          chunk sees every previously appended chunk
        * cross attention: cross_kv supplies (k, v) precomputed; no cache.
     """
@@ -555,70 +526,35 @@ def gqa_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
     else:
         updates = {"k": k, "v": v}
     if cache is not None and cross_kv is None:
-        if sq == 1:  # decode: write one slot, attend over the cache
-            new_cache = _cache_write(cache, updates, q_pos[:, 0])
-            if quant and prefix_len is None and ringc is None:
-                # fused path: codes stream into the kernel and dequantise
-                # in VMEM — the cache is never expanded to bf16/f32 in HBM
-                from repro.kernels import attention as kq
-                with jax.named_scope("attention"):
-                    qg = q[:, 0].reshape(b, kh, h_loc // kh,
-                                         d).astype(jnp.float32)
-                    qg = qg / jnp.sqrt(jnp.float32(d))
-                    o = kq.bp8_decode_attention(
-                        qg, new_cache["k_codes"], new_cache["k_scale"],
-                        new_cache["v_codes"], new_cache["v_scale"],
-                        new_cache["pos"], q_pos[:, 0], window,
-                        softcap=cfg.logit_softcap, causal=causal)
-                out = o.reshape(b, 1, h_loc, -1)
-                k_all = v_all = kv_pos = None
-            elif quant:
-                # prefix-LM or ring-sharded decode: attend the dequantised
-                # cache (the fused kernel is single-device)
-                from repro.kernels import attention as kq
-                k_all = kq.dequantize_kv(new_cache["k_codes"],
-                                         new_cache["k_scale"])
-                v_all = kq.dequantize_kv(new_cache["v_codes"],
-                                         new_cache["v_scale"])
-                kv_pos = new_cache["pos"]
-            else:
-                k_all, v_all, kv_pos = (new_cache["k"], new_cache["v"],
-                                        new_cache["pos"])
-        elif append:  # chunked prefill: append, attend over the full cache
-            new_cache = _cache_append(cache, updates, q_pos)
-            if quant:
-                from repro.kernels import attention as kq
-                k_all = kq.dequantize_kv(new_cache["k_codes"],
-                                         new_cache["k_scale"])
-                v_all = kq.dequantize_kv(new_cache["v_codes"],
-                                         new_cache["v_scale"])
-            else:
-                k_all, v_all = new_cache["k"], new_cache["v"]
-            kv_pos = new_cache["pos"]
-        else:        # prefill: dense write (ring caches keep the last n
-            # tokens at slots pos % n, matching decode's addressing)
-            n = cache["pos"].shape[1]
-            if n < sq:
-                slots = jnp.arange(sq - n, sq) % n
-                new_cache = {key: cache[key].at[:, slots].set(
-                    val[:, sq - n:].astype(cache[key].dtype))
-                    for key, val in updates.items()}
-                new_cache["pos"] = cache["pos"].at[:, slots].set(
-                    q_pos[:, sq - n:])
-            else:
-                new_cache = {key: cache[key].at[:, :sq].set(
-                    val.astype(cache[key].dtype))
-                    for key, val in updates.items()}
-                new_cache["pos"] = cache["pos"].at[:, :sq].set(q_pos)
-            if quant:
-                # attend the values the cache actually stores, so decode
-                # over the quantised cache reproduces prefill's logits
-                from repro.kernels import attention as kq
-                k_all = kq.dequantize_kv(kc, ks)
-                v_all = kq.dequantize_kv(vc, vs)
-            else:
-                k_all, v_all = k, v
-            kv_pos = q_pos
+        # decode writes one slot a row, a chunk appends; either attends
+        # over the whole cache
+        new_cache = (_cache_write(cache, updates, q_pos[:, 0]) if sq == 1
+                     else _cache_append(cache, updates, q_pos))
+        kv_pos = new_cache["pos"]
+        if quant and sq == 1 and prefix_len is None and ringc is None:
+            # fused path: codes stream into the kernel and dequantise
+            # in VMEM — the cache is never expanded to bf16/f32 in HBM
+            from repro.kernels import attention as kq
+            with jax.named_scope("attention"):
+                qg = q[:, 0].reshape(b, kh, h_loc // kh,
+                                     d).astype(jnp.float32)
+                qg = qg / jnp.sqrt(jnp.float32(d))
+                o = kq.bp8_decode_attention(
+                    qg, new_cache["k_codes"], new_cache["k_scale"],
+                    new_cache["v_codes"], new_cache["v_scale"],
+                    kv_pos, q_pos[:, 0], window,
+                    softcap=cfg.logit_softcap, causal=causal)
+            out = o.reshape(b, 1, h_loc, -1)
+        elif quant:
+            # a chunk, or a prefix-LM or ring-sharded decode: attend the
+            # dequantised cache (the fused kernel is single-device)
+            from repro.kernels import attention as kq
+            k_all = kq.dequantize_kv(new_cache["k_codes"],
+                                     new_cache["k_scale"])
+            v_all = kq.dequantize_kv(new_cache["v_codes"],
+                                     new_cache["v_scale"])
+        else:
+            k_all, v_all = new_cache["k"], new_cache["v"]
     else:
         k_all, v_all = k, v
         kv_pos = (q_pos if cross_kv is None else
@@ -715,13 +651,13 @@ def _mla_q(p, cfg, x, tp_attn=False):
 
 
 def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
-              cache: Optional[Dict] = None, window=None, append: bool = False):
-    """MLA attention.  Prefill/train expands K/V from the latent; decode
-    uses the absorbed formulation (scores in the kv_lora latent space), so
-    the per-step cost is O(S * kv_lora) instead of O(S * H * head_dim).
-    ``append`` (chunked prefill): latents are appended at [p0, p0+Sq) and
-    K/V are expanded from the WHOLE cache, so the chunk attends every
-    previously appended chunk."""
+              cache: Optional[Dict] = None, window=None):
+    """MLA attention.  Train and chunked prefill expand K/V from the
+    latent; decode (Sq == 1 with a cache) uses the absorbed formulation
+    (scores in the kv_lora latent space), so the per-step cost is
+    O(S * kv_lora) instead of O(S * H * head_dim).  A chunk's latents are
+    appended at [p0, p0+Sq) and K/V are expanded from the WHOLE cache, so
+    the chunk attends every previously appended chunk."""
     from repro.dist import tp as mtp
     b, sq, _ = x.shape
     mode = cfg.matmul_mode
@@ -778,38 +714,18 @@ def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
                 pr = jax.nn.softmax(scores, axis=-1)
                 o_lat = jnp.einsum("bhqs,bsr->bqhr", pr, ckv_all)  # (B,1,H,R)
         out = jnp.einsum("bqhr,rhv->bqhv", o_lat, p["wuv"].astype(jnp.float32))
-    elif cache is not None and append:
-        # ---- chunked prefill: append latents, expand K/V from the full
-        # cache (bf16-stored latents, the same rounding absorbed decode
-        # reads), attend over every previously appended chunk ----
-        new_cache = _cache_append(cache, {"ckv": ckv, "krope": krope}, q_pos)
-        ckv_all = new_cache["ckv"].astype(jnp.float32)
-        kr_all = new_cache["krope"].astype(jnp.float32)
-        kv_pos = new_cache["pos"]
-        k_nope = jnp.einsum("bsr,rhd->bshd", ckv_all,
-                            p["wuk"].astype(jnp.float32))
-        v = jnp.einsum("bsr,rhv->bshv", ckv_all,
-                       p["wuv"].astype(jnp.float32))
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(kr_all[:, :, None, :],
-                                      k_nope.shape[:3] + (cfg.qk_rope_head_dim,))],
-            axis=-1)
-        q = jnp.concatenate([q_nope.astype(jnp.float32),
-                             q_rope.astype(jnp.float32)], axis=-1)
-        if mscale != 1.0:
-            q = q * (mscale * mscale)
-        out = sdpa(q, k, v, q_pos, kv_pos, causal=True, window=window,
-                   chunk=cfg.attn_chunk)
     else:
-        # ---- expanded train/prefill ----
+        # ---- expanded: train over the sequence, or a chunk appended to
+        # the cache over the whole cache (bf16-stored latents, the same
+        # rounding absorbed decode reads) ----
+        new_cache, ckv_e, kr_e, kv_pos = None, ckv, krope, q_pos
         if cache is not None:
-            # prefill must expand from the SAME bf16-rounded latents it
-            # stores, so later absorbed decode reproduces its logits
-            ckv_e = ckv.astype(jnp.bfloat16).astype(jnp.float32)
-            kr_e = krope.astype(jnp.bfloat16).astype(jnp.float32)
-        else:
-            ckv_e = ckv.astype(jnp.float32)
-            kr_e = krope.astype(jnp.float32)
+            new_cache = _cache_append(cache, {"ckv": ckv, "krope": krope},
+                                      q_pos)
+            ckv_e, kr_e = new_cache["ckv"], new_cache["krope"]
+            kv_pos = new_cache["pos"]
+        ckv_e = ckv_e.astype(jnp.float32)
+        kr_e = kr_e.astype(jnp.float32)
         if tp_attn:
             # the shared latents enter head-sharded compute here: the k/v
             # expansions and (kr broadcast into k) per-head scores
@@ -827,15 +743,8 @@ def mla_apply(p, cfg: ModelConfig, x: jax.Array, positions: jax.Array, *,
                              q_rope.astype(jnp.float32)], axis=-1)
         if mscale != 1.0:
             q = q * (mscale * mscale)
-        out = sdpa(q, k, v, q_pos, q_pos, causal=True, window=window,
+        out = sdpa(q, k, v, q_pos, kv_pos, causal=True, window=window,
                    chunk=cfg.attn_chunk)
-        new_cache = cache
-        if cache is not None:  # prefill: store latents
-            new_cache = {
-                "ckv": cache["ckv"].at[:, :sq].set(ckv.astype(cache["ckv"].dtype)),
-                "krope": cache["krope"].at[:, :sq].set(krope.astype(cache["krope"].dtype)),
-                "pos": cache["pos"].at[:, :sq].set(q_pos),
-            }
     out = out.reshape(b, sq, -1).astype(x.dtype)
     out = dense(out, p["wo"], mode)
     if tp_attn:

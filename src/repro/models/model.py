@@ -11,9 +11,11 @@
 API (all functional, pytree params):
   schema()                      -> ParamDef tree
   loss(params, batch)           -> (scalar loss, metrics dict)      [train]
-  prefill(params, batch)        -> (last-position logits, cache)
-  decode_step(params, tok, cache, pos) -> (logits, cache)
   cache_spec(batch, length)     -> abstract cache pytree (+ logical axes)
+  prefill_chunk(params, batch, cache, pos0) -> (last-position logits, cache)
+  decode_step(params, tok, cache, pos)      -> (logits, cache), pos (B,)
+  prefill(params, batch, cache_len) -> prefill_chunk at position 0 into
+                                    a fresh cache (``Serving``)
 
 Layer stacks are scanned (jax.lax.scan over stacked params) so compile time
 and HLO size are O(1) in depth; heterogeneous stacks scan over groups.
@@ -37,9 +39,23 @@ from repro.models.layers import (ParamDef, chunked_softmax_xent, dense,
                                  embed_def, embed_lookup, layer_norm, ln_defs,
                                  linear_def, mlp_apply, mlp_defs, norm_def,
                                  rms_norm)
-from repro.models.params import abstract_tree, axes_tree, stack
+from repro.models.params import abstract_tree, axes_tree, empty_cache, stack
 
 BIG_WINDOW = 1 << 30  # "no window" sentinel usable as a traced int
+
+
+class Serving:
+    """The one serving contract of every family: a chunk appended to a
+    cache at a traced ``pos0`` (``prefill_chunk``), one token a row at
+    (B,) positions (``decode_step``), and ``prefill``, which is the chunk
+    path at position 0 into a fresh cache."""
+
+    def prefill(self, params, batch, cache_len: int):
+        """-> (last-position logits, a cache of ``cache_len`` tokens, plus
+        the prefix where the config has one)."""
+        spec = self.cache_spec(batch["tokens"].shape[0],
+                               cache_len + self.cfg.num_prefix_tokens)
+        return self.prefill_chunk(params, batch, empty_cache(spec), 0)
 
 
 # =============================================================================
@@ -76,18 +92,17 @@ def _layer_windows(cfg: ModelConfig) -> np.ndarray:
 
 
 def _decoder_layer_apply(p, cfg: ModelConfig, x, positions, *, window,
-                         cache=None, prefix_len=None, append=False):
+                         cache=None, prefix_len=None):
     """-> (x, new cache, aux loss, the MoE layer's routes: None for a dense
     layer or outside serving)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.attention_type == "mla":
         a, new_cache = attn.mla_apply(p["attn"], cfg, h, positions,
-                                      cache=cache, window=window,
-                                      append=append)
+                                      cache=cache, window=window)
     else:
         a, new_cache = attn.gqa_apply(p["attn"], cfg, h, positions,
                                       window=window, cache=cache,
-                                      prefix_len=prefix_len, append=append)
+                                      prefix_len=prefix_len)
     if "post_ln1" in p:
         a = rms_norm(a, p["post_ln1"], cfg.norm_eps)
     x = x + a
@@ -105,7 +120,7 @@ def _decoder_layer_apply(p, cfg: ModelConfig, x, positions, *, window,
 
 
 @dataclasses.dataclass
-class DecoderModel:
+class DecoderModel(Serving):
     cfg: ModelConfig
 
     # ---------------- schema ----------------
@@ -127,7 +142,7 @@ class DecoderModel:
         return sch
 
     # ---------------- shared forward over the stack ----------------
-    def _stack(self, params, x, positions, caches, prefix_len, mode: str):
+    def _stack(self, params, x, positions, caches, prefix_len):
         cfg = self.cfg
         windows = _layer_windows(cfg)
         aux_total = jnp.float32(0.0)
@@ -147,11 +162,10 @@ class DecoderModel:
             def layer_fn(x, lp, lcache, w):
                 return _decoder_layer_apply(lp, cfg, x, positions, window=w,
                                             cache=lcache,
-                                            prefix_len=prefix_len,
-                                            append=mode == "prefill_chunk")
+                                            prefix_len=prefix_len)
 
             fn = (jax.checkpoint(layer_fn)
-                  if (cfg.remat and mode == "train") else layer_fn)
+                  if (cfg.remat and caches is None) else layer_fn)
 
             def body(carry, inp):
                 x, aux = carry
@@ -204,6 +218,11 @@ class DecoderModel:
             x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
         return x
 
+    def _prefix(self, b):
+        """(B,) prefix lengths for the prefix-LM mask, None without one."""
+        n = self.cfg.num_prefix_tokens
+        return jnp.full((b,), n, jnp.int32) if n else None
+
     @jax.named_scope("head")
     def _logits(self, params, h):
         cfg = self.cfg
@@ -220,9 +239,7 @@ class DecoderModel:
         x = self._embed_in(params, batch)
         b, s, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        prefix = (jnp.full((b,), cfg.num_prefix_tokens, jnp.int32)
-                  if cfg.num_prefix_tokens else None)
-        h, _, aux = self._stack(params, x, positions, None, prefix, "train")
+        h, _, aux = self._stack(params, x, positions, None, self._prefix(b))
         if cfg.num_prefix_tokens:
             h = h[:, cfg.num_prefix_tokens:]
         labels = batch["labels"]
@@ -374,11 +391,7 @@ class DecoderModel:
 
     def cache_spec(self, batch: int, length: int):
         cfg = self.cfg
-        ring = cfg.ring_cache
-        if ring:
-            # ring caches require every layer windowed (uniform SWA)
-            assert cfg.window_size and not cfg.local_global_pattern, cfg.name
-        one = attn.kv_cache_spec(cfg, batch, length, ring=ring)
+        one = attn.kv_cache_spec(cfg, batch, length)
         n_dense = cfg.first_dense_layers
         n_rest = cfg.num_layers - n_dense
         out = {"layers": jax.tree.map(
@@ -396,22 +409,6 @@ class DecoderModel:
             out["dense_layers"] = one
         return out
 
-    def prefill(self, params, batch, cache_len: int):
-        cfg = self.cfg
-        x = self._embed_in(params, batch)
-        b, s, _ = x.shape
-        # the cache must also hold the prefix (e.g. paligemma image tokens)
-        cache = jax.tree.map(lambda sp: (jnp.full(sp.shape, -1, sp.dtype)
-                                         if sp.dtype == jnp.int32 else
-                                         jnp.zeros(sp.shape, sp.dtype)),
-                             self.cache_spec(b, cache_len + cfg.num_prefix_tokens))
-        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        prefix = (jnp.full((b,), cfg.num_prefix_tokens, jnp.int32)
-                  if cfg.num_prefix_tokens else None)
-        h, cache, _ = self._stack(params, x, positions, cache, prefix, "prefill")
-        logits = self._logits(params, h[:, -1:])
-        return logits[:, 0], cache
-
     def prefill_chunk(self, params, batch, cache, pos0):
         """Chunked prefill: append a chunk at positions [pos0, pos0+C).
 
@@ -420,34 +417,29 @@ class DecoderModel:
         is written at offsets [pos0, pos0+C).  ``pos0`` is a traced scalar,
         so one compile covers every chunk of the same (C, cache_len) shape
         — the paged engine decomposes prompts into power-of-two chunks for
-        an O(log) compile footprint.  Returns (last-token logits, cache).
-        With an expert share (``experts_held``) the cache also holds
-        ``moe.ROUTES``: (B, C, MoE layers, k), the held expert each routed
-        pair went to, -1 where it is not held here.
+        an O(log) compile footprint.  A first chunk may carry ``patches``
+        (prefix tokens ahead of its text, the prefix-LM mask over them).
+        Returns (last-token logits, cache).  With an expert share
+        (``experts_held``) the cache also holds ``moe.ROUTES``: (B, C, MoE
+        layers, k), the held expert each routed pair went to, -1 where it
+        is not held here.
         """
-        cfg = self.cfg
-        assert cfg.num_prefix_tokens == 0, "chunked prefill: no prefix tokens"
-        tokens = batch["tokens"]
-        b, s = tokens.shape
-        x = embed_lookup(params["embed"], tokens,
-                         scale=cfg.local_global_pattern > 0)
+        x = self._embed_in(params, batch)
+        b, s, _ = x.shape
         positions = jnp.broadcast_to(
             jnp.asarray(pos0) + jnp.arange(s)[None], (b, s))
-        h, cache, _ = self._stack(params, x, positions, cache, None,
-                                  "prefill_chunk")
+        h, cache, _ = self._stack(params, x, positions, cache,
+                                  self._prefix(b))
         logits = self._logits(params, h[:, -1:])
         return logits[:, 0], cache
 
     def decode_step(self, params, tokens, cache, pos):
-        """One token a row at per-row positions ``pos``; -> (logits,
+        """One token a row at per-row positions ``pos`` (B,); -> (logits,
         cache), the cache with ``moe.ROUTES`` as in ``prefill_chunk``."""
-        cfg = self.cfg
-        x = embed_lookup(params["embed"], tokens,
-                         scale=cfg.local_global_pattern > 0 or
-                         cfg.num_prefix_tokens > 0)
+        x = self._embed_in(params, {"tokens": tokens})
         b = x.shape[0]
-        positions = _decode_positions(pos, b)
-        h, cache, _ = self._stack(params, x, positions, cache, None, "decode")
+        h, cache, _ = self._stack(params, x, jnp.reshape(pos, (b, 1)), cache,
+                                  None)
         logits = self._logits(params, h)
         return logits[:, 0], cache
 
@@ -457,14 +449,6 @@ def _tied_head(h, embed):
     """(B, D) hidden states -> (B, V) logits over the tied embedding."""
     return jnp.einsum("bd,vd->bv", h.astype(jnp.float32),
                       embed.astype(jnp.float32))
-
-
-def _decode_positions(pos, b):
-    """(B, 1) positions from a scalar (lock-step) or (B,) (paged) pos."""
-    pos = jnp.asarray(pos)
-    if pos.ndim == 1:
-        return pos.reshape(b, 1)
-    return jnp.broadcast_to(pos[None, None], (b, 1))
 
 
 def _none_like(tree):
@@ -496,7 +480,7 @@ def _dec_layer_defs(cfg: ModelConfig):
 
 
 @dataclasses.dataclass
-class EncDecModel:
+class EncDecModel(Serving):
     cfg: ModelConfig
 
     def schema(self):
@@ -533,15 +517,14 @@ class EncDecModel:
         return layer_norm(x, params["enc_norm"]["gamma"],
                           params["enc_norm"]["beta"], cfg.norm_eps)
 
-    def _decode_stack(self, params, x, positions, enc_out, caches, mode,
+    def _decode_stack(self, params, x, positions, enc_out, caches,
                       cross_cache=None):
-        """enc_out drives cross attention in train/prefill; decode instead
-        reads per-layer cross K/V cached at prefill (computing them once
-        instead of re-projecting the encoder output every token —
-        EXPERIMENTS.md §Roofline whisper-decode note)."""
+        """enc_out drives cross attention in training and in a first
+        prefill chunk; later chunks and decode read the per-layer cross
+        K/V that chunk cached (computed once, not re-projected from the
+        encoder output every token)."""
         cfg = self.cfg
         kh, hd = cfg.num_kv_heads, cfg.head_dim
-        append = mode == "prefill_chunk"
 
         def body(carry, inp):
             x, = carry
@@ -549,8 +532,7 @@ class EncDecModel:
             lcache = _as_cache(lcache)
             h = layer_norm(x, lp["ln1"]["gamma"], lp["ln1"]["beta"], cfg.norm_eps)
             a, ncache = attn.gqa_apply(lp["self_attn"], cfg, h, positions,
-                                       window=None, cache=lcache, rope=False,
-                                       append=append)
+                                       window=None, cache=lcache, rope=False)
             x = x + a
             h = layer_norm(x, lp["ln_x"]["gamma"], lp["ln_x"]["beta"], cfg.norm_eps)
             if lcross is not None:
@@ -570,7 +552,7 @@ class EncDecModel:
                          "v": cv.astype(jnp.bfloat16)}
             return (x,), (ncache, new_cross)
 
-        body_fn = (jax.checkpoint(body) if (cfg.remat and mode == "train")
+        body_fn = (jax.checkpoint(body) if (cfg.remat and caches is None)
                    else body)
         cc = caches if caches is not None else _none_like(params["dec_layers"])
         xc = (cross_cache if cross_cache is not None
@@ -593,8 +575,7 @@ class EncDecModel:
         b, s = tokens.shape
         x = embed_lookup(params["embed"], tokens) + params["pos_embed"][None, :s]
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        h, _, _ = self._decode_stack(params, x, positions, enc_out, None,
-                                     "train")
+        h, _, _ = self._decode_stack(params, x, positions, enc_out, None)
         mask = batch.get("loss_mask", jnp.ones_like(batch["labels"], jnp.float32))
         total, denom = chunked_softmax_xent(h, params["embed"],
                                             batch["labels"], mask)
@@ -623,23 +604,6 @@ class EncDecModel:
                  "v": ("stack", "batch", "frames", "kv_heads", None)}
         return {"self": one, "cross": cross}
 
-    def prefill(self, params, batch, cache_len: int):
-        cfg = self.cfg
-        enc_out = self.encode(params, batch["frames"])
-        tokens = batch["tokens"]
-        b, s = tokens.shape
-        spec = self.cache_spec(b, cache_len)
-        cache = jax.tree.map(lambda sp: (jnp.full(sp.shape, -1, sp.dtype)
-                                         if sp.dtype == jnp.int32 else
-                                         jnp.zeros(sp.shape, sp.dtype)), spec)
-        x = embed_lookup(params["embed"], tokens) + params["pos_embed"][None, :s]
-        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        h, selfc, cross = self._decode_stack(params, x, positions, enc_out,
-                                             cache["self"], "prefill")
-        cache = {"self": selfc, "cross": cross}
-        logits = _tied_head(h[:, -1], params["embed"])
-        return logits, cache
-
     def prefill_chunk(self, params, batch, cache, pos0):
         """Chunked prefill.  The first chunk carries ``frames`` and runs
         the encoder (filling the per-layer cross K/V cache); later chunks
@@ -659,24 +623,18 @@ class EncDecModel:
         positions = jnp.broadcast_to(
             jnp.asarray(pos0) + jnp.arange(s)[None], (b, s))
         h, selfc, cross = self._decode_stack(params, x, positions, enc_out,
-                                             cache["self"], "prefill_chunk",
+                                             cache["self"],
                                              cross_cache=cross_cache)
         logits = _tied_head(h[:, -1], params["embed"])
         return logits, {"self": selfc, "cross": cross}
 
     def decode_step(self, params, tokens, cache, pos):
-        cfg = self.cfg
         b = tokens.shape[0]
         x = embed_lookup(params["embed"], tokens)
-        pos = jnp.asarray(pos)
-        if pos.ndim == 1:  # paged decode: per-row learned positions
-            x = x + jnp.take(params["pos_embed"], pos, axis=0)[:, None]
-        else:
-            x = x + jax.lax.dynamic_slice_in_dim(params["pos_embed"],
-                                                 pos, 1, axis=0)[None]
-        positions = _decode_positions(pos, b)
-        h, selfc, cross = self._decode_stack(params, x, positions, None,
-                                             cache["self"], "decode",
+        x = x + jnp.take(params["pos_embed"], pos, axis=0)[:, None]
+        h, selfc, cross = self._decode_stack(params, x,
+                                             jnp.reshape(pos, (b, 1)), None,
+                                             cache["self"],
                                              cross_cache=cache["cross"])
         logits = _tied_head(h[:, 0], params["embed"])
         return logits, {"self": selfc, "cross": cross}
@@ -687,7 +645,7 @@ class EncDecModel:
 # =============================================================================
 
 @dataclasses.dataclass
-class HybridModel:
+class HybridModel(Serving):
     cfg: ModelConfig
 
     def _group_dims(self):
@@ -717,11 +675,10 @@ class HybridModel:
             "lora": lora,
         }
 
-    def _forward(self, params, x, positions, caches, mode):
+    def _forward(self, params, x, positions, caches):
         cfg = self.cfg
         n_groups, per = self._group_dims()
         shared = params["shared"]
-        append = mode == "prefill_chunk"
 
         def group_body(carry, inp):
             x, = carry
@@ -738,7 +695,7 @@ class HybridModel:
                                 else jnp.zeros((0,)))
 
             mamba_fn = (jax.checkpoint(mamba_body)
-                        if (cfg.remat and mode == "train") else mamba_body)
+                        if (cfg.remat and caches is None) else mamba_body)
             mc = (gcache["mamba"] if gcache is not None else
                   _none_like(gp))
             x, new_mc = jax.lax.scan(mamba_fn, x, (gp, mc))
@@ -746,7 +703,7 @@ class HybridModel:
             h = rms_norm(x, shared["ln1"], cfg.norm_eps)
             ac = gcache["attn"] if gcache is not None else None
             a, new_ac = attn.gqa_apply(shared["attn"], cfg, h, positions,
-                                       window=None, cache=ac, append=append)
+                                       window=None, cache=ac)
             a = a + dense(dense(h, lora_p["a_q"], "bf16"), lora_p["b_q"],
                           "bf16")
             x = x + a
@@ -767,7 +724,7 @@ class HybridModel:
         b, s = tokens.shape
         x = embed_lookup(params["embed"], tokens)
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        h, _ = self._forward(params, x, positions, None, "train")
+        h, _ = self._forward(params, x, positions, None)
         mask = batch.get("loss_mask", jnp.ones_like(batch["labels"], jnp.float32))
         total, denom = chunked_softmax_xent(h, params["embed"],
                                             batch["labels"], mask)
@@ -791,19 +748,6 @@ class HybridModel:
                  "ssm": ("stack", "stack2", "batch", "heads", None, "state")}
         return {"mamba": mamba, "attn": attn.kv_cache_axes(self.cfg)}
 
-    def prefill(self, params, batch, cache_len: int):
-        tokens = batch["tokens"]
-        b, s = tokens.shape
-        spec = self.cache_spec(b, cache_len)
-        cache = jax.tree.map(lambda sp: (jnp.full(sp.shape, -1, sp.dtype)
-                                         if sp.dtype == jnp.int32 else
-                                         jnp.zeros(sp.shape, sp.dtype)), spec)
-        x = embed_lookup(params["embed"], tokens)
-        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        h, cache = self._forward(params, x, positions, cache, "prefill")
-        logits = _tied_head(h[:, -1], params["embed"])
-        return logits, cache
-
     def prefill_chunk(self, params, batch, cache, pos0):
         """Chunked prefill: the attention KV caches append at offsets
         [pos0, pos0+C); the mamba conv/SSM states carry across chunks
@@ -814,15 +758,14 @@ class HybridModel:
         x = embed_lookup(params["embed"], tokens)
         positions = jnp.broadcast_to(
             jnp.asarray(pos0) + jnp.arange(s)[None], (b, s))
-        h, cache = self._forward(params, x, positions, cache, "prefill_chunk")
+        h, cache = self._forward(params, x, positions, cache)
         logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def decode_step(self, params, tokens, cache, pos):
         b = tokens.shape[0]
         x = embed_lookup(params["embed"], tokens)
-        positions = _decode_positions(pos, b)
-        h, cache = self._forward(params, x, positions, cache, "decode")
+        h, cache = self._forward(params, x, jnp.reshape(pos, (b, 1)), cache)
         logits = _tied_head(h[:, 0], params["embed"])
         return logits, cache
 
@@ -832,7 +775,7 @@ class HybridModel:
 # =============================================================================
 
 @dataclasses.dataclass
-class XLSTMModel:
+class XLSTMModel(Serving):
     cfg: ModelConfig
 
     def _group_dims(self):
@@ -853,7 +796,7 @@ class XLSTMModel:
                             "block": ssm_mod.slstm_defs(cfg)}, n_groups),
         }
 
-    def _forward(self, params, x, caches, mode):
+    def _forward(self, params, x, caches):
         cfg = self.cfg
 
         def group_body(carry, inp):
@@ -871,7 +814,7 @@ class XLSTMModel:
                                 else jnp.zeros((0,)))
 
             m_fn = (jax.checkpoint(m_body)
-                    if (cfg.remat and mode == "train") else m_body)
+                    if (cfg.remat and caches is None) else m_body)
             mc = gcache["mlstm"] if gcache is not None else _none_like(mp)
             x, new_mc = jax.lax.scan(m_fn, x, (mp, mc))
             h = rms_norm(x, sp["ln"], cfg.norm_eps)
@@ -891,7 +834,7 @@ class XLSTMModel:
     def loss(self, params, batch):
         tokens = batch["tokens"]
         x = embed_lookup(params["embed"], tokens)
-        h, _ = self._forward(params, x, None, "train")
+        h, _ = self._forward(params, x, None)
         mask = batch.get("loss_mask", jnp.ones_like(batch["labels"], jnp.float32))
         total, denom = chunked_softmax_xent(h, params["embed"],
                                             batch["labels"], mask)
@@ -919,16 +862,6 @@ class XLSTMModel:
              "m": ("stack", "batch", "heads")}
         return {"mlstm": m, "slstm": s}
 
-    def prefill(self, params, batch, cache_len: int):
-        tokens = batch["tokens"]
-        b = tokens.shape[0]
-        spec = self.cache_spec(b, cache_len)
-        cache = jax.tree.map(lambda sp: jnp.zeros(sp.shape, sp.dtype), spec)
-        x = embed_lookup(params["embed"], tokens)
-        h, cache = self._forward(params, x, cache, "prefill")
-        logits = _tied_head(h[:, -1], params["embed"])
-        return logits, cache
-
     def prefill_chunk(self, params, batch, cache, pos0):
         """Chunked prefill: pure recurrent state, so a chunk is just a
         forward pass continuing from the stored per-slot state (``pos0``
@@ -936,13 +869,13 @@ class XLSTMModel:
         del pos0
         tokens = batch["tokens"]
         x = embed_lookup(params["embed"], tokens)
-        h, cache = self._forward(params, x, cache, "prefill_chunk")
+        h, cache = self._forward(params, x, cache)
         logits = _tied_head(h[:, -1], params["embed"])
         return logits, cache
 
     def decode_step(self, params, tokens, cache, pos):
         x = embed_lookup(params["embed"], tokens)
-        h, cache = self._forward(params, x, cache, "decode")
+        h, cache = self._forward(params, x, cache)
         logits = _tied_head(h[:, 0], params["embed"])
         return logits, cache
 

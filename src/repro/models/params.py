@@ -7,6 +7,9 @@ From one schema we derive:
   * ``abstract_tree`` — ShapeDtypeStructs (for the dry-run; no allocation)
   * ``axes_tree``     — logical-axis tuples per leaf (for sharding rules)
 
+``empty_cache`` materialises a model's ``cache_spec`` the same way: every
+serving cache (a fresh contiguous one, the paged pool) starts empty.
+
 Logical axis names (mapped to mesh axes by repro.dist.sharding.Rules):
   layers, d_model, ffn, heads, kv_heads, head_dim, vocab, experts, lora,
   state, conv, frames, norm (never sharded), stack (scan groups)
@@ -65,6 +68,17 @@ def abstract_tree(schema):
 
 def axes_tree(schema):
     return jax.tree.map(lambda d: d.axes, schema, is_leaf=is_def)
+
+
+def empty_value(dtype) -> int:
+    """What an empty cache leaf holds: -1 for int32 positions, else 0."""
+    return -1 if dtype == jnp.int32 else 0
+
+
+def empty_cache(spec):
+    """A fresh cache from a ``cache_spec`` tree of ShapeDtypeStructs."""
+    return jax.tree.map(
+        lambda s: jnp.full(s.shape, empty_value(s.dtype), s.dtype), spec)
 
 
 def stack(schema, n: int, axis_name: str = "stack"):

@@ -348,10 +348,8 @@ def slstm_apply(p, cfg: ModelConfig, x: jax.Array, state=None):
     gx = gx.reshape(bs, s, 4, h, hd)
     r = p["r"].astype(jnp.float32)
 
-    if state is None:
-        c0 = jnp.zeros((bs, h, hd), jnp.float32)
-        n0 = jnp.ones((bs, h, hd), jnp.float32)
-        h0 = jnp.zeros((bs, h, hd), jnp.float32)
+    if state is None:   # the empty state a serving cache starts from
+        c0 = n0 = h0 = jnp.zeros((bs, h, hd), jnp.float32)
         m0 = jnp.zeros((bs, h), jnp.float32)
     else:
         c0, n0, h0, m0 = state["c"], state["n"], state["h"], state["m"]

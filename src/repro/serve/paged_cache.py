@@ -1,12 +1,12 @@
 """Paged KV cache: per-slot block tables over a shared physical pool.
 
-The contiguous engine gives every slot ``max_len`` cache positions up
-front, so one long request dictates the allocation of every short one.
-Here the sequence axis is cut into fixed ``block_size`` blocks, pooled
+A contiguous cache gives every slot ``max_len`` positions up front, so
+one long request dictates the allocation of every short one.  Here the
+sequence axis is cut into fixed ``block_size`` blocks, pooled
 across slots, and each slot holds a *block table* — an ordered list of
 physical block ids whose concatenation is that slot's logical cache.
 Blocks are reserved at admission and returned when the request retires,
-so long and short requests share memory with no left-pad contiguity.
+so long and short requests share memory.
 
 Layout falls out of the models' ``cache_axes`` names, family-agnostic:
 
@@ -17,7 +17,7 @@ Layout falls out of the models' ``cache_axes`` names, family-agnostic:
   (layers, heads x dim) lie flattened along the last, padded to whole
   lanes;
 * leaves without one (SSM/xLSTM recurrent states, encoder-decoder cross
-  attention) are dense per slot, exactly as in the contiguous engine.
+  attention) are dense per slot, as in a contiguous cache.
 
 Models never see blocks.  For each step the engine *gathers* a dense
 view — ``(rows, V)`` tokens, ``V`` a power-of-two bucket — runs the
@@ -72,14 +72,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.models.params import empty_cache, empty_value
+
 NULL_BLOCK = 0
 LANES = 128     # a TPU vector register's lanes: a pool row's unit
-
-
-def init_value(dtype) -> int:
-    """What a pool leaf starts as: -1 for int32 positions (empty), else 0.
-    The null block is never written, so it keeps this value for good."""
-    return -1 if dtype == jnp.int32 else 0
 
 
 def round_up_pow2(n: int) -> int:
@@ -239,7 +235,7 @@ def _programs(leaves: Sequence[_Leaf], block_size: int):
         n_rows, n_blk = table.shape
         width = _window_blocks(n_blk, bs)
         view = [jnp.full(leaf.lead + (n_rows, n_blk * bs) + leaf.tail,
-                         init_value(x.dtype), x.dtype) if leaf.width else
+                         empty_value(x.dtype), x.dtype) if leaf.width else
                 jnp.zeros(x.shape[:leaf.bi] + (n_rows,)
                           + x.shape[leaf.bi + 1:], x.dtype)
                 for x, leaf in zip(pool, leaves)]
@@ -305,7 +301,7 @@ def _programs(leaves: Sequence[_Leaf], block_size: int):
                                                  x.dtype), (blocks[k], 0, 0))))
         return [lax.dynamic_update_slice(
             x, jnp.full(x.shape[:bi[i]] + (1,) + x.shape[bi[i] + 1:],
-                        init_value(x.dtype), x.dtype), _start(x, bi[i], slot))
+                        empty_value(x.dtype), x.dtype), _start(x, bi[i], slot))
                 if i in dense else x for i, x in enumerate(pool)]
 
     return (jax.jit(cache_gather),
@@ -317,9 +313,8 @@ def _programs(leaves: Sequence[_Leaf], block_size: int):
 class PagedCache:
     """Physical pool + block tables + gather/commit cache surgery."""
 
-    def __init__(self, model, cfg, *, slots: int, num_blocks: int,
+    def __init__(self, model, *, slots: int, num_blocks: int,
                  block_size: int):
-        assert not cfg.ring_cache, "paged cache layers a ring itself"
         assert block_size & (block_size - 1) == 0, "block_size must be 2^k"
         assert num_blocks >= 2, "need at least the null block plus one"
         self.slots, self.num_blocks = slots, num_blocks
@@ -333,8 +328,7 @@ class PagedCache:
         self._axes = jax.tree.leaves(axes,
                                      is_leaf=lambda x: isinstance(x, tuple))
         specs, leaves = zip(*_leaves(model, slots, num_blocks, block_size))
-        self._pool: List[jnp.ndarray] = [
-            jnp.full(sp.shape, init_value(sp.dtype), sp.dtype) for sp in specs]
+        self._pool: List[jnp.ndarray] = empty_cache(list(specs))
         (self._gather, self._commit_decode, self._commit_prefill,
          self._scrub) = _programs(leaves, block_size)
 

@@ -21,8 +21,7 @@ Engine loop (one ``step()``):
 Every jitted call sees only bucketed shapes — chunk lengths are powers
 of two capped by ``max_prefill_tokens``, dense-view lengths are
 power-of-two block counts, the decode batch is constant — so the compile
-count is O(log max_len) where the reference engine retraced per refill
-length.  ``stats`` records the distinct shapes so tests can pin that
+count is O(log max_len), not one per prompt length.  ``stats`` records the distinct shapes so tests can pin that
 bound.
 
 Time is measured in engine steps (one ``step()`` = one unit), which
@@ -191,12 +190,11 @@ class PagedServeEngine:
 
     def __init__(self, model, params, cfg: ModelConfig,
                  ecfg: PagedEngineConfig, obs=None):
-        assert not cfg.ring_cache, "paged engine: ring cache unsupported"
         assert cfg.num_prefix_tokens == 0, \
             "paged engine: prefix tokens (vlm) unsupported"
         assert ecfg.max_prefill_tokens & (ecfg.max_prefill_tokens - 1) == 0
         self.model, self.params, self.cfg, self.ecfg = model, params, cfg, ecfg
-        self.cache = PagedCache(model, cfg, slots=ecfg.slots,
+        self.cache = PagedCache(model, slots=ecfg.slots,
                                 num_blocks=ecfg.num_blocks,
                                 block_size=ecfg.block_size)
         self.scheduler = PriorityScheduler(ecfg.num_blocks - 1,

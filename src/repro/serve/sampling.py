@@ -1,9 +1,10 @@
-"""Counter-based token sampling, shared by both serving engines.
+"""Counter-based token sampling, shared by the serving engine and the
+tests' reference.
 
 Temperature sampling is keyed on ``(seed, rid, step)`` via
 ``jax.random.fold_in`` + ``jax.random.categorical``: the token a request
 samples at step *t* is a pure function of its own logits and identity.
-That makes sampled streams bit-stable across runs, engines, and batch
+That makes sampled streams bit-stable across runs and batch
 compositions — which slot a request lands in, or which neighbours share
 its decode batch, cannot perturb its randomness.
 

@@ -6,6 +6,7 @@ import pytest
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as A
+from repro.models.params import empty_cache
 
 
 def _qkv(rng, b=2, sq=64, skv=64, h=4, kh=2, d=16):
@@ -88,7 +89,7 @@ def test_mla_absorbed_decode_matches_expanded(rng):
     out_full, _ = A.mla_apply(params, cfg, x, pos_full)
     # prefill 8 tokens, then decode token 8
     spec = A.kv_cache_spec(cfg, 2, 9)
-    cache = A.init_cache(spec)
+    cache = empty_cache(spec)
     _, cache = A.mla_apply(params, cfg, x[:, :8],
                            jnp.broadcast_to(jnp.arange(8)[None], (2, 8)),
                            cache=cache)
@@ -97,14 +98,3 @@ def test_mla_absorbed_decode_matches_expanded(rng):
     np.testing.assert_allclose(np.asarray(out_dec[:, 0]),
                                np.asarray(out_full[:, 8]),
                                rtol=2e-2, atol=2e-2)
-
-
-def test_ring_cache_write():
-    spec = {"k": jax.ShapeDtypeStruct((1, 4, 2, 3), jnp.bfloat16),
-            "v": jax.ShapeDtypeStruct((1, 4, 2, 3), jnp.bfloat16),
-            "pos": jax.ShapeDtypeStruct((1, 4), jnp.int32)}
-    cache = A.init_cache(spec)
-    k = jnp.ones((1, 1, 2, 3), jnp.bfloat16)
-    for p in range(6):  # wraps around length-4 ring
-        cache = A._cache_write(cache, {"k": k * p, "v": k * p}, jnp.int32(p))
-    assert cache["pos"][0].tolist() == [4, 5, 2, 3]

@@ -3,9 +3,9 @@
 The cache stores int8 sign*level codes plus one f32 scale per
 (token, kv-head).  Every leaf keeps "batch" at the same index and
 "kv_seq" right after it, so the paged block pool handles the quantised
-cache with zero engine changes — which the served-alone vs paged token
-equality below demonstrates end to end (decode runs the fused
-``bp8_decode_attention`` kernel over gathered block views).
+cache with zero engine changes — which the paged engine's token
+equality with the reference below demonstrates end to end (decode runs
+the fused ``bp8_decode_attention`` kernel over gathered block views).
 """
 import dataclasses
 
@@ -19,9 +19,10 @@ from repro.launch.inputs import demo_batch
 from repro.models import attention as attn
 from repro.models import build
 from repro.models.params import init_tree
-from repro.serve.engine import EngineConfig, Request, ServeEngine
 from repro.serve.paged_engine import (PagedEngineConfig, PagedRequest,
                                       PagedServeEngine)
+
+from _reference import serve_alone
 
 
 def _cfg(name="h2o_danube_1p8b", **kw):
@@ -81,7 +82,7 @@ def test_kv_quant_unknown_rejected():
 
 
 # ---------------------------------------------------------------------------
-# serving equivalence: contiguous served-alone vs paged, both on bp8 KV
+# serving equivalence: the reference served alone vs paged, both on bp8 KV
 # ---------------------------------------------------------------------------
 
 def _prompts(seed, n, lo, hi, vocab):
@@ -92,18 +93,14 @@ def _prompts(seed, n, lo, hi, vocab):
 
 def test_paged_bp8_kv_matches_contiguous():
     """The paged engine decodes through the fused bp8 attention kernel
-    over gathered block views; the contiguous engine serves each request
-    alone with the same quantised cache.  Greedy streams must match
-    token for token."""
+    over gathered block views; the reference serves each request alone
+    over a contiguous cache, quantised the same way.  Greedy streams must
+    match token for token."""
     cfg = _cfg(kv_quant="bp8")
     model = build(cfg)
     params = init_tree(model.schema(), jax.random.key(0))
     prompts = _prompts(0, 4, 3, 14, cfg.vocab_size)
-    ref = {}
-    for i, p in enumerate(prompts):
-        eng = ServeEngine(model, params, cfg,
-                          EngineConfig(slots=1, max_len=64))
-        ref.update(eng.run([Request(rid=i, prompt=p, max_new_tokens=5)]))
+    ref = serve_alone(model, params, cfg, prompts, max_new=5)
     paged = PagedServeEngine(model, params, cfg,
                              PagedEngineConfig(slots=2, block_size=8,
                                                num_blocks=32,
@@ -116,21 +113,15 @@ def test_paged_bp8_kv_matches_contiguous():
 def test_bp8_kv_decode_close_to_bf16_kv():
     """Quantising the cache perturbs logits by the KV round-trip error
     only — greedy continuations of a tiny random model stay identical or
-    near-identical to the bf16-cache engine (sanity that the quantised
-    path computes attention, not noise)."""
+    near-identical to the bf16 cache's (sanity that the quantised path
+    computes attention, not noise)."""
     cfg = _cfg()
     model = build(cfg)
     params = init_tree(model.schema(), jax.random.key(0))
     prompts = _prompts(1, 3, 4, 12, cfg.vocab_size)
 
     def run(c):
-        m = build(c)
-        out = {}
-        for i, p in enumerate(prompts):
-            eng = ServeEngine(m, params, c, EngineConfig(slots=1, max_len=64))
-            out.update(eng.run([Request(rid=i, prompt=p,
-                                        max_new_tokens=4)]))
-        return out
+        return serve_alone(build(c), params, c, prompts, max_new=4)
 
     bf16 = run(cfg)
     bp8 = run(_cfg(kv_quant="bp8"))

@@ -1,5 +1,6 @@
 """Per-architecture smoke tests (reduced configs) + consistency checks."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +11,8 @@ from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ShapeConfig
 from repro.launch.inputs import demo_batch
 from repro.models import build
+from repro.models.layers import embed_lookup
+from repro.models.model import _tied_head
 from repro.models.params import init_tree
 
 TRAIN = ShapeConfig("t", "train", 64, 2)
@@ -43,12 +46,13 @@ def test_train_step_smoke(arch, built):
 def test_prefill_decode_shapes(arch, built):
     cfg, model, params = built[arch]
     pb = demo_batch(cfg, PREFILL)
-    logits, cache = jax.jit(model.prefill, static_argnums=2)(params, pb, 64)
+    # room for the 64 prompt tokens and the one decoded after them
+    logits, cache = jax.jit(model.prefill, static_argnums=2)(params, pb, 65)
     assert logits.shape == (2, cfg.vocab_size)
     assert jnp.isfinite(logits).all()
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-    logits2, cache2 = jax.jit(model.decode_step)(params, tok, cache,
-                                                 jnp.int32(64))
+    pos = jnp.full((2,), 64 + cfg.num_prefix_tokens, jnp.int32)
+    logits2, cache2 = jax.jit(model.decode_step)(params, tok, cache, pos)
     assert logits2.shape == (2, cfg.vocab_size)
     assert jnp.isfinite(logits2).all()
     # cache structure preserved
@@ -75,7 +79,8 @@ def test_decode_matches_prefill(arch, built):
     atol = 0.15 if cfg.attention_type == "mla" else 2e-2
     for i in range(3):
         nxt = toks[:, t0 + i: t0 + i + 1]
-        got, cache = decode(params, nxt, cache, jnp.int32(t0 + i))
+        got, cache = decode(params, nxt, cache,
+                            jnp.full((2,), t0 + i, jnp.int32))
         pb_ref = dict(pb, tokens=toks[:, : t0 + i + 1])
         want, _ = prefill(params, pb_ref, 64)
         np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -83,6 +88,53 @@ def test_decode_matches_prefill(arch, built):
                                    rtol=5e-2, atol=atol)
         assert (np.argmax(np.asarray(got), -1)
                 == np.argmax(np.asarray(want), -1)).all()
+
+
+def _train_logits(model, params, batch):
+    """Last-position logits of the training forward (no cache)."""
+    cfg, tokens = model.cfg, batch["tokens"]
+    b, s = tokens.shape
+    if cfg.family == "decoder":     # prefix tokens ahead of the text
+        x = model._embed_in(params, batch)
+        positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None],
+                                     x.shape[:2])
+        h, _, _ = model._stack(params, x, positions, None, model._prefix(b))
+        return model._logits(params, h[:, -1:])[:, 0]
+    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    x = embed_lookup(params["embed"], tokens)
+    if cfg.family == "encdec":
+        x = x + params["pos_embed"][None, :s]
+        h, _, _ = model._decode_stack(params, x, positions,
+                                      model.encode(params, batch["frames"]),
+                                      None)
+    elif cfg.family == "hybrid":
+        h, _ = model._forward(params, x, positions, None)
+    else:
+        h, _ = model._forward(params, x, None)
+    return _tied_head(h[:, -1], params["embed"])
+
+
+@pytest.mark.parametrize("arch", ["h2o_danube_1p8b", "minicpm3_4b",
+                                  "zamba2_2p7b", "xlstm_1p3b",
+                                  "whisper_base", "paligemma_3b"])
+def test_prefill_matches_train_forward(arch, built):
+    """``prefill`` is the chunk path at position 0 into a fresh cache: its
+    last-position logits are the training forward's at that position
+    (GQA windowed, MLA, hybrid, xLSTM, encoder-decoder, and prefix
+    tokens through the chunk path)."""
+    cfg, model, params = built[arch]
+    pb = demo_batch(cfg, PREFILL, seed=3)
+    pb = dict(pb, tokens=pb["tokens"][:, :40])
+    got, _ = jax.jit(model.prefill, static_argnums=2)(params, pb, 64)
+    want = jax.jit(functools.partial(_train_logits, model))(params, pb)
+    # the tolerances of test_decode_matches_prefill: MLA's chunk path
+    # expands K/V from the bf16 latents it caches, training from f32
+    atol = 0.15 if cfg.attention_type == "mla" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=5e-2, atol=atol)
+    assert (np.argmax(np.asarray(got), -1)
+            == np.argmax(np.asarray(want), -1)).all()
 
 
 @pytest.mark.parametrize("arch, scopes", [
@@ -141,32 +193,3 @@ def test_paligemma_prefix_attention(built):
     batch2 = dict(batch, patches=p1 + 1.0)
     loss2, _ = jax.jit(model.loss)(params, batch2)
     assert abs(float(loss1) - float(loss2)) > 1e-6  # prefix affects loss
-
-
-def test_ring_cache_decode(built):
-    """Ring-buffer SWA cache (window slots only) must reproduce the
-    full-length-cache decode logits exactly — the long_500k memory
-    optimisation (EXPERIMENTS.md §Perf E)."""
-    cfg, model, params = built["h2o_danube_1p8b"]  # uniform SWA window 16
-    cfg_ring = dataclasses.replace(cfg, ring_cache=True)
-    model_ring = build(cfg_ring)
-    pb = demo_batch(cfg, PREFILL, seed=11)
-    prefill = jax.jit(model.prefill, static_argnums=2)
-    prefill_r = jax.jit(model_ring.prefill, static_argnums=2)
-    lf, cache_full = prefill(params, pb, 64)          # cache len 64
-    lr, cache_ring = prefill_r(params, pb, 64)        # cache len 16 (window)
-    assert cache_ring["layers"]["k"].shape[2] == cfg.window_size
-    np.testing.assert_allclose(np.asarray(lr, np.float32),
-                               np.asarray(lf, np.float32), rtol=2e-2,
-                               atol=2e-2)
-    decode = jax.jit(model.decode_step)
-    decode_r = jax.jit(model_ring.decode_step)
-    tok = jnp.argmax(lf, -1)[:, None].astype(jnp.int32)
-    for i in range(3):  # decode past the prefill, wrapping the ring
-        gf, cache_full = decode(params, tok, cache_full, jnp.int32(64 + i))
-        gr, cache_ring = decode_r(params, tok, cache_ring, jnp.int32(64 + i))
-        np.testing.assert_allclose(np.asarray(gr, np.float32),
-                                   np.asarray(gf, np.float32), rtol=2e-2,
-                                   atol=2e-2)
-        tok = jnp.argmax(gf, -1)[:, None].astype(jnp.int32)
-        assert (jnp.argmax(gr, -1) == jnp.argmax(gf, -1)).all()

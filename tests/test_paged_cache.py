@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.serve.paged_cache import LANES, NULL_BLOCK, PagedCache, init_value
+from repro.models.params import empty_value
+from repro.serve.paged_cache import LANES, NULL_BLOCK, PagedCache
 
 SLOTS, NUM_BLOCKS, BS = 4, 12, 4
 RESERVED = {0: 3, 1: 1, 2: 4}                       # slot 3 stays empty
@@ -41,10 +42,6 @@ class _Model:
                 "ssm": ("stack", "stack2", "batch", "heads", "state")}
 
 
-class _Cfg:
-    ring_cache = False
-
-
 def _random(shape, dtype, rng):
     if dtype == jnp.int32:
         return rng.integers(0, 1000, shape).astype(np.int32)
@@ -57,14 +54,14 @@ def _cache(seed=0, churn=True, num_blocks=NUM_BLOCKS, reserved=RESERVED):
     """A cache with random pool contents and the slots of ``reserved``
     allocated; the null block holds every kv leaf's init value (-1
     positions, zeros), as the pool keeps it."""
-    cache = PagedCache(_Model(), _Cfg(), slots=SLOTS, num_blocks=num_blocks,
+    cache = PagedCache(_Model(), slots=SLOTS, num_blocks=num_blocks,
                        block_size=BS)
     rng = np.random.default_rng(seed)
     pool = []
     for leaf, ax in zip(cache._pool, cache._axes):
         a = _random(leaf.shape, leaf.dtype, rng)
         if "kv_seq" in ax:
-            a[NULL_BLOCK] = init_value(leaf.dtype)
+            a[NULL_BLOCK] = empty_value(leaf.dtype)
         pool.append(jnp.asarray(a))
     cache._pool = pool
     if churn:       # tables scattered over the pool, else runs of blocks
@@ -171,7 +168,7 @@ def _null_block_is_untouched(cache):
     for leaf, (is_kv, bi) in zip(cache._pool, _kinds(cache)):
         if is_kv:
             assert (np.array(leaf)[NULL_BLOCK]
-                    == init_value(leaf.dtype)).all()
+                    == empty_value(leaf.dtype)).all()
 
 
 # -- the cases -----------------------------------------------------------

@@ -391,7 +391,7 @@ from repro.dist import seq as msq
 from repro.dist import sharding as shd
 from repro.launch.mesh import make_host_mesh
 from repro.models import attention as A
-from repro.models.params import init_tree
+from repro.models.params import empty_cache, init_tree
 
 mesh = make_host_mesh(model=2, seq=4)   # (4, 1, 2)
 rules = shd.get_rules("sequence")
@@ -420,7 +420,7 @@ spec = A.kv_cache_spec(cfg, b, L)
 
 def gqa_prefill(pos):
     _, cache = A.gqa_apply(params, cfg, x, pos, window=None,
-                           cache=A.init_cache(spec))
+                           cache=empty_cache(spec))
     return cache
 
 def gqa_decode(x1, p1, cache):
@@ -439,7 +439,7 @@ x = jnp.asarray(rng.normal(size=(b, L - 1, dm)), jnp.float32)
 spec = A.kv_cache_spec(cfg, b, L)
 
 def mla_prefill(pos):
-    _, cache = A.mla_apply(params, cfg, x, pos, cache=A.init_cache(spec))
+    _, cache = A.mla_apply(params, cfg, x, pos, cache=empty_cache(spec))
     return cache
 
 def mla_decode(x1, p1, cache):

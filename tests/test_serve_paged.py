@@ -1,12 +1,9 @@
-"""Paged serving engine: token-equivalence with the contiguous engine,
-bounded retrace, admission control, pool-reuse hygiene.
+"""Paged serving engine: token-equivalence with the engine-free
+reference, bounded retrace, admission control, pool-reuse hygiene.
 
-The reference for equivalence is the contiguous engine serving each
-request *alone* (slots=1): with no neighbours there is no left-padding,
-so its stream is the model's true greedy/sampled continuation.  (The
-contiguous engine's *batched* streams differ by construction — left-pad
-tokens are attended — which is one of the artifacts the paged layout
-removes.)
+The reference (``_reference.serve_alone``) serves each request alone
+with the model's ``prefill`` and ``decode_step`` over a contiguous
+cache: its stream is the model's true greedy/sampled continuation.
 """
 import jax
 import numpy as np
@@ -15,9 +12,10 @@ import pytest
 from repro.configs import get_config
 from repro.models import build
 from repro.models.params import init_tree
-from repro.serve.engine import EngineConfig, Request, ServeEngine
 from repro.serve.paged_engine import (PagedEngineConfig, PagedRequest,
                                       PagedServeEngine)
+
+from _reference import serve_alone
 
 FAMILIES = ["h2o_danube_1p8b", "whisper_base", "zamba2_2p7b"]
 
@@ -36,18 +34,6 @@ def _prompts(seed, n, lo, hi, vocab):
                          ).astype(np.int32) for _ in range(n)]
 
 
-def _served_alone(model, params, cfg, prompts, max_new, temperature=0.0,
-                  seed=0):
-    out = {}
-    for i, p in enumerate(prompts):
-        eng = ServeEngine(model, params, cfg,
-                          EngineConfig(slots=1, max_len=64,
-                                       temperature=temperature))
-        out.update(eng.run([Request(rid=i, prompt=p, max_new_tokens=max_new)],
-                           seed=seed))
-    return out
-
-
 def _paged(cfg, model, params, **kw):
     defaults = dict(slots=2, block_size=8, num_blocks=32,
                     max_prefill_tokens=8)
@@ -57,11 +43,11 @@ def _paged(cfg, model, params, **kw):
 
 
 def test_paged_matches_contiguous_greedy(stack):
-    """Heterogeneous paged batch == contiguous served-alone, tokenwise —
+    """Heterogeneous paged batch == the reference served alone, tokenwise —
     with more requests than slots, so admission happens mid-stream."""
     cfg, model, params = stack
     prompts = _prompts(0, 5, 3, 20, cfg.vocab_size)
-    ref = _served_alone(model, params, cfg, prompts, max_new=6)
+    ref = serve_alone(model, params, cfg, prompts, max_new=6)
     eng = _paged(cfg, model, params, slots=2)
     reqs = [PagedRequest(rid=i, prompt=p, max_new_tokens=6)
             for i, p in enumerate(prompts)]
@@ -93,13 +79,13 @@ def test_paged_pool_reuse_is_scrubbed(stack):
 
 def test_paged_temperature_matches_contiguous():
     """Counter-based sampling keyed on (seed, rid, step): the sampled
-    stream survives the engine swap bit-for-bit."""
+    stream is the reference's, bit for bit."""
     cfg = get_config("h2o_danube_1p8b", smoke=True)
     model = build(cfg)
     params = init_tree(model.schema(), jax.random.key(0))
     prompts = _prompts(3, 4, 3, 14, cfg.vocab_size)
-    ref = _served_alone(model, params, cfg, prompts, max_new=6,
-                        temperature=0.8, seed=7)
+    ref = serve_alone(model, params, cfg, prompts, max_new=6,
+                      temperature=0.8, seed=7)
     eng = _paged(cfg, model, params, slots=3, temperature=0.8)
     got = eng.run([PagedRequest(rid=i, prompt=p, max_new_tokens=6)
                    for i, p in enumerate(prompts)], seed=7)

@@ -89,8 +89,7 @@ def test_slstm_decode_matches_full(rng):
     x = jnp.asarray(rng.standard_normal((2, 10, cfg.d_model)) * 0.5,
                     jnp.float32)
     full, _ = ssm.slstm_apply(params, cfg, x, state=None)
-    state = {k: (jnp.ones(v.shape, v.dtype) if k == "n"
-                 else jnp.zeros(v.shape, v.dtype))
+    state = {k: jnp.zeros(v.shape, v.dtype)          # the empty state
              for k, v in ssm.slstm_state_spec(cfg, 2).items()}
     for t in range(10):
         out_t, state = ssm.slstm_apply(params, cfg, x[:, t:t + 1],

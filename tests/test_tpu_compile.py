@@ -154,7 +154,7 @@ def _abstract_cache(cfg, slots, blocks):
     made = []
 
     def make():
-        made.append(PagedCache(build(cfg), cfg, slots=slots,
+        made.append(PagedCache(build(cfg), slots=slots,
                                num_blocks=blocks, block_size=BLOCK))
         return made[0]._pool
 
